@@ -47,6 +47,7 @@ from common import artifacts_dir, carry_smoke_ref, time_it, update_smoke_ref
 from repro.core import build as build_mod
 from repro.core import knobs as knobs_mod
 from repro.kernels import ops
+from repro import compile_cache
 
 
 def _prune_case(rng, table_np, chunk, C, d):
@@ -256,4 +257,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     sys.exit(main())

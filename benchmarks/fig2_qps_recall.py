@@ -12,6 +12,7 @@ import numpy as np
 
 from benchmarks import common
 from repro.core import SearchConfig, baselines
+from repro import compile_cache
 
 EFS = (16, 48, 96)
 WORKLOADS = ("frac_2", "frac_8", "mixed")
@@ -68,4 +69,5 @@ def run(quick=False, n_queries=64):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     common.emit(run())

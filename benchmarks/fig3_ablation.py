@@ -5,6 +5,7 @@ from __future__ import annotations
 
 from benchmarks import common
 from repro.core import SearchConfig, baselines
+from repro import compile_cache
 
 EFS = (32, 96)
 
@@ -41,4 +42,5 @@ def run(quick=False):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     common.emit(run())

@@ -8,6 +8,7 @@ import numpy as np
 
 from benchmarks import common
 from repro.core import SearchConfig, baselines
+from repro import compile_cache
 
 EFS = (16, 48, 96)
 
@@ -52,4 +53,5 @@ def run(quick=False):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     common.emit(run())

@@ -9,6 +9,7 @@ import numpy as np
 
 from benchmarks import common
 from repro.core import SearchConfig, multiattr
+from repro import compile_cache
 
 EFS = (32, 96)
 
@@ -63,4 +64,5 @@ def run(quick=False):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     common.emit(run())

@@ -80,6 +80,7 @@ from repro.kernels import gather_distance as gather_k
 from repro.kernels import hop as hop_k
 from repro.kernels import ops
 from repro.kernels import prune as prune_k
+from repro import compile_cache
 
 
 def _elemental_table(rng, n, m, logn):
@@ -697,4 +698,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import common
 from repro.core import bitset
 from repro.kernels import ops
+from repro import compile_cache
 
 
 def _best_s(fn, *args, iters=10):
@@ -364,4 +365,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     sys.exit(main())

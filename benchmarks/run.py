@@ -12,6 +12,8 @@ import argparse
 import sys
 import time
 
+from repro import compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -60,4 +62,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

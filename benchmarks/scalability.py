@@ -8,6 +8,7 @@ import numpy as np
 from benchmarks import common
 from repro.core import BuildConfig, RangeGraphIndex, SearchConfig, recall
 from repro.data.pipeline import vector_dataset
+from repro import compile_cache
 
 
 def run(quick=False):
@@ -35,4 +36,5 @@ def run(quick=False):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     common.emit(run())

@@ -47,6 +47,7 @@ from repro.core import SearchConfig, ServeConfig
 from repro.serve import AsyncServingEngine, DeadlineExceededError, \
     FaultConfig, OverloadedError, Request, SearchExecutor, ShedError, \
     ShutdownError
+from repro import compile_cache
 
 OUTCOMES = ("ok", "rejected", "shed", "timeout", "shutdown", "failed")
 
@@ -247,4 +248,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -8,6 +8,7 @@ import numpy as np
 
 from benchmarks import common
 from repro.core import storage as storage_mod
+from repro import compile_cache
 
 
 def run(quick=False):
@@ -59,4 +60,5 @@ def run(quick=False):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     common.emit(run())

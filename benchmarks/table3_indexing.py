@@ -9,6 +9,7 @@ import numpy as np
 from benchmarks import common
 from repro.core import BuildConfig, build_flat_graph, build_neighbor_table
 from repro.data.pipeline import vector_dataset
+from repro import compile_cache
 
 
 def run(quick=False):
@@ -37,4 +38,5 @@ def run(quick=False):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     common.emit(run())

@@ -102,7 +102,7 @@ def _level_sizes(n: int) -> tuple[int, int]:
 
 
 def _reverse_pass(
-    nbrs_lay: np.ndarray, vectors, vec_j, seg_of, cfg: BuildConfig,
+    nbrs_lay: np.ndarray, vec_j, seg_of, cfg: BuildConfig,
     chunk: int | None = None,
 ):
     """Add reverse edges then re-prune each node's list. numpy + fused prune.
@@ -116,7 +116,7 @@ def _reverse_pass(
     """
     n, m = nbrs_lay.shape
     if chunk is None:
-        chunk = resolve_chunk(cfg, 3 * m, np.asarray(vectors).shape[1])
+        chunk = resolve_chunk(cfg, 3 * m, vec_j.shape[1])
     # collect reverse candidates: for edge (u, v) add u to v's pool (capped)
     us = np.repeat(np.arange(n, dtype=np.int32), m)
     vs = nbrs_lay.reshape(-1)
@@ -136,12 +136,11 @@ def _reverse_pass(
     cand[:, :m] = nbrs_lay
     cand[vs[keep], m + pos[keep]] = us[keep]
     out = np.empty((n, m), np.int32)
-    vecs = np.asarray(vectors)
     for s in range(0, n, chunk):
         e = min(n, s + chunk)
         ids = jnp.asarray(cand[s:e])
-        cvec = jnp.asarray(vecs[np.maximum(cand[s:e], 0)])
-        u_vec = jnp.asarray(vecs[s:e])
+        cvec = vec_j[jnp.maximum(ids, 0)]
+        u_vec = vec_j[s:e]
         d = jnp.sum((cvec - u_vec[:, None, :]) ** 2, axis=-1)
         d = jnp.where(ids >= 0, d, jnp.inf)
         out[s:e] = np.asarray(
@@ -197,7 +196,7 @@ def build_neighbor_table(
         rev_chunk = None
         if cfg.add_reverse:
             rev_chunk = resolve_chunk(cfg, 3 * m, d)
-            edges = _reverse_pass(edges, vectors, vec_j, seg_of, cfg,
+            edges = _reverse_pass(edges, vec_j, seg_of, cfg,
                                   rev_chunk)
         nbrs[:, lay, :] = edges
         if level_times is not None:
@@ -246,8 +245,9 @@ def _build_search_level(vec_j, nbrs, n, lay, logn, size, cfg: BuildConfig,
                         chunk):
     """Own-child copy + sibling beam search, then prune. Paper §3.2.2."""
     m, efc = cfg.m, cfg.ef_construction
-    child_lay = lay + 1
-    nbrs_j = jnp.asarray(nbrs)  # children of this level are already built
+    # the children of this level are already built; only their layer is
+    # read, so only it goes to the device
+    child = jnp.asarray(nbrs[:, lay + 1:lay + 2, :])    # int32[n, 1, m]
     out = np.empty((n, m), np.int32)
     half = size // 2
     search_cfg = SearchConfig(ef=efc)
@@ -260,10 +260,10 @@ def _build_search_level(vec_j, nbrs, n, lay, logn, size, cfg: BuildConfig,
         sib_lo = jnp.where(in_left, mid + 1, lo)
         sib_hi = jnp.where(in_left, lo + size - 1, mid)
         res = search_mod.search_fixed_layer(
-            vec_j, nbrs_j, vec_j[u], sib_lo, sib_hi,
-            layer=child_lay, k=efc, config=search_cfg,
+            vec_j, child, vec_j[u], sib_lo, sib_hi,
+            layer=0, k=efc, config=search_cfg,
         )
-        own = nbrs_j[u, child_lay, :]                   # int32[B, m]
+        own = child[u, 0, :]                            # int32[B, m]
         cand = jnp.concatenate([own, res.ids], axis=1)  # [B, m + efc]
         valid = (cand >= 0) & (cand != u[:, None]) & (cand < n)
         cand = jnp.where(valid, cand, -1)

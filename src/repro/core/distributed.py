@@ -22,6 +22,7 @@ shard_map path and mesh-free hosts.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import functools
 
 import jax
@@ -72,6 +73,22 @@ class ShardedRangeIndex:
         return (self.vectors.nbytes + self.neighbors.nbytes
                 + self.bounds.nbytes)
 
+    def place(self, mesh: Mesh):
+        """Upload the shards straight to their devices: each stacked table
+        goes up with ``NamedSharding(mesh, P("data"))``, so shard ``s``
+        lands on the ``data``-axis device that serves it (replicated over
+        ``model``) instead of whole on one device first. Neighbors go up
+        in the ``storage.EdgeRows`` form the hop kernel reads. Returns the
+        ``(vectors, neighbors, bounds)`` triple ``rfann_serve_step``
+        takes."""
+        put = functools.partial(
+            jax.device_put, device=NamedSharding(mesh, P("data")))
+        packed = [storage_mod.pack_edges(t) for t in self.neighbors]
+        rows = put(np.stack([p.rows for p in packed]))
+        return (put(self.vectors),
+                storage_mod.EdgeRows(rows, packed[0].layers, packed[0].m),
+                put(self.bounds))
+
 
 def build_sharded(
     vectors: np.ndarray, attrs: np.ndarray, n_shards: int,
@@ -79,8 +96,8 @@ def build_sharded(
     storage: storage_mod.StorageConfig | None = None,
 ) -> ShardedRangeIndex:
     """Sort globally by attribute, chunk into contiguous rank ranges, build
-    one index per shard (embarrassingly parallel across hosts in a real
-    deployment).
+    one index per shard — in parallel, one shard per local device (and
+    embarrassingly parallel across hosts in a real deployment).
 
     ``n_shards`` need not divide ``n``: shards are ``ceil(n / n_shards)``
     wide and a ragged tail is padded by repeating its last vector row, with
@@ -106,8 +123,7 @@ def build_sharded(
     order = np.argsort(attrs, kind="stable")
     vs = np.asarray(vectors, np.float32)[order]
     per = -(-n // n_shards)  # ceil: the last shard may be ragged
-    vlist, nlist, bounds = [], [], []
-    logn = None
+    slices, bounds = [], []
     for s in range(n_shards):
         lo = s * per
         hi = min(lo + per, n) - 1  # hi < lo marks an all-padding shard
@@ -117,14 +133,26 @@ def build_sharded(
             sl = np.concatenate(
                 [sl, np.broadcast_to(fill, (per - sl.shape[0], vs.shape[1]))]
             )
-        tbl = build_mod.build_neighbor_table(sl, cfg, storage=storage)
-        vlist.append(storage_mod.encode_vectors(sl, storage))
-        nlist.append(tbl)
+        slices.append(sl)
         bounds.append((lo, hi))
-        logn = tbl.shape[1] - 1
+    # shards are independent: on accelerators shard s builds on local
+    # device s (mod the count), all devices at once; host devices share
+    # the host's cores, so there the shards build one after another
+    devices = jax.local_devices()
+
+    def build(s):
+        with jax.default_device(devices[s % len(devices)]):
+            return build_mod.build_neighbor_table(slices[s], cfg,
+                                                  storage=storage)
+
+    workers = 1 if devices[0].platform == "cpu" else \
+        min(len(devices), n_shards)
+    with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+        tables = list(pool.map(build, range(n_shards)))
     return ShardedRangeIndex(
-        np.stack(vlist), np.stack(nlist), np.asarray(bounds, np.int32),
-        logn, cfg.m, storage,
+        np.stack([storage_mod.encode_vectors(sl, storage) for sl in slices]),
+        np.stack(tables), np.asarray(bounds, np.int32),
+        tables[0].shape[1] - 1, cfg.m, storage,
     )
 
 
@@ -141,7 +169,8 @@ def shard_topk(
     plain per-shard on hosts (tests, single-process serving).
 
     vec [n_shard, d] (any storage dtype); nbr [n_shard, layers, m] (any
-    neighbor codec); bnd i32[2] the shard's real global rank range; q
+    neighbor codec) or ``storage.EdgeRows``; bnd i32[2] the shard's real
+    global rank range; q
     [B, d]; Lq/Rq i32[B] global rank ranges. Engine knobs come from
     ``config`` (loose kwargs = deprecation shim). Returns (ids, dists)
     [B, k] with ids global (-1 padded) and dists inf-padded.
@@ -150,9 +179,8 @@ def shard_topk(
         config, ef=ef, expand_width=expand_width, dist_impl=dist_impl,
         edge_impl=edge_impl, _warn_where="shard_topk",
     )
-    # compact storage: ids widen through the one -1-preserving decode
-    # (core/storage.py); vectors stay bf16/f16 down to the kernels
-    nbr = storage_mod.decode_neighbors(nbr)
+    # any neighbor codec passes through: the search packs it into the
+    # EdgeRows form once per call; vectors stay bf16/f16 down to the kernels
     lo, hi = bnd[0], bnd[1]
     # clip the global range to this shard's rank range, local coords;
     # hi is the REAL range end, so any padded tail rows stay > Rl and are
@@ -190,7 +218,8 @@ def merge_topk(all_ids, all_d, k):
 
 def rfann_serve_step(
     shard_vectors,    # f32/bf16[S, n_shard, d]   sharded: ("data", None, None)
-    shard_neighbors,  # i32/i16[S, n_shard, layers, m]  sharded likewise
+    shard_neighbors,  # i32/i16[S, n_shard, layers, m] or stacked EdgeRows
+                      # (``ShardedRangeIndex.place``), sharded likewise
     shard_bounds,     # i32[S, 2]
     queries,          # f32[B, d]            sharded: ("model", None)
     L, R,             # i32[B] global rank ranges
@@ -216,9 +245,11 @@ def rfann_serve_step(
     query_spec = P(("pod", "model")) if have_pod else P("model")
 
     def local(vec, nbr, bnd, q, Lq, Rq):
-        # leading shard dim is mapped over the data axis
+        # leading shard dim is mapped over the data axis (per leaf, so a
+        # stacked EdgeRows / codec struct unstacks too)
+        nbr = jax.tree.map(lambda a: a[0], nbr)
         ids, dists = shard_topk(
-            vec[0], nbr[0], bnd[0], q, Lq, Rq,
+            vec[0], nbr, bnd[0], q, Lq, Rq,
             logn=logn, m=m, k=k, config=config,
         )
         # merge across the data axis: gather all shards' top-k

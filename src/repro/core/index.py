@@ -32,7 +32,9 @@ from repro.core import config as config_mod
 from repro.core import search as search_mod
 from repro.core import storage as storage_mod
 
-__all__ = ["IndexCorruptionError", "RangeGraphIndex"]
+__all__ = [
+    "IndexCorruptionError", "RangeGraphIndex", "brute_force", "recall",
+]
 
 
 class IndexCorruptionError(IOError):
@@ -222,27 +224,8 @@ class RangeGraphIndex:
     # -- ground truth ---------------------------------------------------------
     def brute_force(self, queries, L, R, *, k=10, metric="l2"):
         """Exact in-range top-k (== the Pre-filtering strategy). numpy."""
-        q = np.asarray(queries, np.float32)
-        L = np.asarray(L)
-        R = np.asarray(R)
-        vecs = storage_mod.decode_vectors(self.vectors)  # numpy edge: f32
-        ids = np.full((q.shape[0], k), -1, np.int64)
-        dists = np.full((q.shape[0], k), np.inf, np.float32)
-        for i in range(q.shape[0]):
-            lo, hi = int(L[i]), int(R[i])
-            if hi < lo:
-                continue
-            x = vecs[lo : hi + 1]
-            if metric == "l2":
-                d = ((x - q[i]) ** 2).sum(1)
-            else:
-                d = -(x @ q[i])
-            kk = min(k, d.shape[0])
-            part = np.argpartition(d, kk - 1)[:kk]
-            part = part[np.argsort(d[part], kind="stable")]
-            ids[i, :kk] = part + lo
-            dists[i, :kk] = d[part]
-        return ids, dists
+        return brute_force(storage_mod.decode_vectors(self.vectors),
+                           queries, L, R, k=k, metric=metric)
 
     # -- serialization ---------------------------------------------------------
     def save(self, path: str):
@@ -348,6 +331,32 @@ class RangeGraphIndex:
             storage=storage_mod.StorageConfig(**st),
             rerank=rerank,
         )
+
+
+def brute_force(vecs, queries, L, R, *, k=10, metric="l2"):
+    """Exact in-range top-k over rank-ordered f32 ``vecs [n, d]`` for
+    inclusive rank ranges ``[L, R]`` (the Pre-filtering strategy): the
+    ground truth every search path is checked against. numpy."""
+    q = np.asarray(queries, np.float32)
+    L = np.asarray(L)
+    R = np.asarray(R)
+    ids = np.full((q.shape[0], k), -1, np.int64)
+    dists = np.full((q.shape[0], k), np.inf, np.float32)
+    for i in range(q.shape[0]):
+        lo, hi = int(L[i]), int(R[i])
+        if hi < lo:
+            continue
+        x = vecs[lo : hi + 1]
+        if metric == "l2":
+            d = ((x - q[i]) ** 2).sum(1)
+        else:
+            d = -(x @ q[i])
+        kk = min(k, d.shape[0])
+        part = np.argpartition(d, kk - 1)[:kk]
+        part = part[np.argsort(d[part], kind="stable")]
+        ids[i, :kk] = part + lo
+        dists[i, :kk] = d[part]
+    return ids, dists
 
 
 def recall(result_ids, gt_ids) -> float:

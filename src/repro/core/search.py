@@ -369,7 +369,9 @@ def _search_improvised_jit(vectors, nbrs, queries, L, R, rerank_store=None,
     exact codecs) and re-cut to ``k`` — all inside this one jit, so the
     executor's compile accounting sees a single program.
     """
-    nbrs = storage_mod.decode_neighbors(nbrs)
+    # the hop kernels read the EdgeRows form: pack here, once per call and
+    # outside the loop (a no-op for the executor's pre-packed upload)
+    nbrs = storage_mod.pack_edges(nbrs)
     n = storage_mod.table_n(vectors)
     expand_width = effective_expand_width(config.expand_width, config.ef)
     entries = range_entry_ids(L, jnp.minimum(R, n - 1), n)
@@ -421,8 +423,9 @@ def search_improvised(
     """The paper's query path: beam search on the improvised dedicated graph.
 
     L, R: int32[B] per-query inclusive rank ranges. ``vectors``/``nbrs`` may
-    arrive in any storage codec (bf16/f16/int8/PQ vectors, int16/split ids):
-    the neighbor table decodes once here, outside the hop loop; vectors stay
+    arrive in any storage codec (bf16/f16/int8/PQ vectors, int16/split ids
+    or ``storage.EdgeRows``): the neighbor table packs into ``EdgeRows``
+    once here, outside the hop loop; vectors stay
     encoded end-to-end (the distance kernels decode in-register, DESIGN.md
     §9). ``rerank_store`` + ``config.rerank`` enable the in-jit exact
     refinement pass over the sidecar table.

@@ -47,6 +47,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -72,6 +73,8 @@ __all__ = [
     "Int8Vectors",
     "PQVectors",
     "SplitNeighbors",
+    "EdgeRows",
+    "pack_edges",
     "NEIGHBOR_SENTINEL",
     "PQ_CENTROIDS",
 ]
@@ -141,6 +144,55 @@ class SplitNeighbors(NamedTuple):
 
     hi: Any
     lo: Any
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class EdgeRows:
+    """Row-DMA form of a neighbor table, the one the Pallas kernels read.
+
+    rows: int32[n*R, 128] — node ``u``'s ``K = layers*m`` edges (layer
+        major, as in ``[n, layers, m]``) padded with ``-1`` to ``R =
+        ceil(K/128)`` whole 128-lane rows ``u*R .. u*R+R-1``.
+
+    On a TPU an int32 ``[n, layers, m]`` or ``[n, K]`` array gets a
+    node-minor default layout, so a node's edges are not contiguous and a
+    kernel fed that array pays a full-table transpose on every call. Here
+    one node is one contiguous ``R``-row DMA. ``layers`` and ``m`` are
+    static pytree metadata (part of a jit signature, like a codec's
+    structure); :func:`decode_neighbors` unpacks to ``[n, layers, m]``.
+    """
+
+    rows: Any
+    layers: int
+    m: int
+
+    @property
+    def R(self) -> int:
+        return -(-self.layers * self.m // 128)
+
+    def tree_flatten(self):
+        return (self.rows,), (self.layers, self.m)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(children[0], *aux)
+
+
+def pack_edges(nbrs) -> EdgeRows:
+    """Neighbor table (any codec, numpy or jnp) -> :class:`EdgeRows`."""
+    if isinstance(nbrs, EdgeRows):
+        return nbrs
+    nbrs = decode_neighbors(nbrs)
+    n, layers, m = nbrs.shape
+    K = layers * m
+    Kp = -(-K // 128) * 128
+    xp = np if isinstance(nbrs, np.ndarray) else jnp
+    flat = nbrs.reshape(n, K)
+    if Kp != K:
+        flat = xp.pad(flat, ((0, 0), (0, Kp - K)),
+                      constant_values=NEIGHBOR_SENTINEL)
+    return EdgeRows(flat.reshape(n * (Kp // 128), 128), layers, m)
 
 
 def split_layer(logn: int) -> int:
@@ -484,6 +536,11 @@ def decode_neighbors(nbrs):
     """
     if isinstance(nbrs, SplitNeighbors):
         return _decode_split(nbrs)
+    if isinstance(nbrs, EdgeRows):
+        n = nbrs.rows.shape[0] // nbrs.R
+        K = nbrs.layers * nbrs.m
+        return nbrs.rows.reshape(n, nbrs.R * 128)[:, :K].reshape(
+            n, nbrs.layers, nbrs.m)
     if nbrs.dtype == np.int32:
         return nbrs
     return nbrs.astype(jnp.int32 if isinstance(nbrs, jnp.ndarray)
@@ -500,6 +557,8 @@ def table_n(table) -> int:
         return table.codes.shape[0]
     if isinstance(table, SplitNeighbors):
         return table.hi.shape[0]
+    if isinstance(table, EdgeRows):
+        return table.rows.shape[0] // table.R
     return table.shape[0]
 
 
@@ -519,6 +578,8 @@ def table_nbytes(table) -> int:
         return 0
     if isinstance(table, (Int8Vectors, PQVectors, SplitNeighbors)):
         return sum(int(np.asarray(leaf).nbytes) for leaf in table)
+    if isinstance(table, EdgeRows):
+        return int(table.rows.nbytes)
     return int(table.nbytes)
 
 
@@ -533,4 +594,6 @@ def as_device(table):
         return None
     if isinstance(table, (Int8Vectors, PQVectors, SplitNeighbors)):
         return type(table)(*(jnp.asarray(leaf) for leaf in table))
+    if isinstance(table, EdgeRows):
+        return EdgeRows(jnp.asarray(table.rows), table.layers, table.m)
     return jnp.asarray(table)

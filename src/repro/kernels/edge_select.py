@@ -44,7 +44,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import storage as _storage
 from repro.kernels import ref as _ref
+from repro.kernels import rowdma as _rows
 
 __all__ = ["edge_select_kernel_call"]
 
@@ -52,53 +54,26 @@ __all__ = ["edge_select_kernel_call"]
 def _edge_select_kernel(
     meta_smem,   # SMEM [bf, 4] (u, L, R, pad) — DMA row indices
     meta_vmem,   # VMEM [bf, 4] (vectorized u/L/R)
-    table_ref,   # ANY  [n, K]  (packed nbrs, never blocked)
+    table_ref,   # ANY  [n*Rw, 128] (packed nbrs, never blocked)
     o_ref,       # VMEM [bf, m_out]
-    xbuf,        # VMEM scratch [bf, K] gathered edge blocks
+    xbuf,        # VMEM scratch [bf, Rw, 128] gathered edge blocks
     sems,        # DMA semaphores [window]
-    *, bf, K, m, logn, m_out, skip_layers, window, dedup,
+    *, bf, K, Rw, m, logn, m_out, skip_layers, window, dedup,
 ):
     big = jnp.int32(2**30)
 
     def slot_u(t):
         return meta_smem[t, 0]
 
-    def row_copy(t):
-        return pltpu.make_async_copy(
-            table_ref.at[slot_u(t)], xbuf.at[t], sems.at[t % window]
-        )
-
-    def start(t):
-        @pl.when(slot_u(t) >= 0)
-        def _():
-            row_copy(t).start()
-
-    def wait(t):
-        @pl.when(slot_u(t) >= 0)
-        def _():
-            row_copy(t).wait()
-
-    # software-pipelined gather: keep up to `window` row DMAs in flight
-    def fill(t, carry):
-        @pl.when(t >= window)
-        def _():
-            wait(t - window)
-
-        start(t)
-        return carry
-
-    jax.lax.fori_loop(0, bf, fill, 0)
-
-    def drain(t, carry):
-        wait(t)
-        return carry
-
-    jax.lax.fori_loop(max(0, bf - window), bf, drain, 0)
+    _rows.gather(bf, window, slot_u, lambda t: [pltpu.make_async_copy(
+        table_ref.at[pl.ds(slot_u(t) * Rw, Rw)], xbuf.at[t],
+        sems.at[t % window])])
 
     us = meta_vmem[:, 0:1]                                # [bf, 1]
     L = meta_vmem[:, 1:2]
     R = meta_vmem[:, 2:3]
-    flat = xbuf[...]                                      # [bf, K]
+    flat = jnp.concatenate(
+        [xbuf[:, c, :] for c in range(Rw)], axis=1)[:, :K]  # [bf, K]
 
     # scan-mask + in-range validity: the one shared closed form (Mosaic
     # needs the 2D broadcasted iota; everything inside is elementwise)
@@ -153,15 +128,15 @@ def edge_select_kernel_call(
     """Fused per-hop edge improvisation (DESIGN.md §2/§3; oracle:
     ``ref.select_edges``).
 
-    nbrs int16/int32[n, layers, m] (any compact neighbor width, -1
-    sentinel; ``SplitNeighbors`` structs decode before dispatch in
-    ``ops.select_edges``), us int32[F] (-1 masked), L/R scalars or
+    nbrs [n, layers, m] in any neighbor codec (-1 sentinel) or the
+    ``storage.EdgeRows`` row-DMA form, us int32[F] (-1 masked), L/R scalars or
     int32[F] -> int32[F, m_out] improvised edges, -1 padded. Ids are
     bit-identical to the oracle across dtypes and backends.
 
     Pads F to the ``block_f`` row-tile multiple internally; the table is
-    passed flattened ``[n, layers*m]`` so each frontier node is one
-    contiguous row DMA. ``dedup`` picks "lazy" (default, O(m_out*K)) or
+    passed in ``EdgeRows`` form (``storage.pack_edges``, a full-table copy
+    unless it already is) so each frontier node is one contiguous DMA.
+    ``dedup`` picks "lazy" (default, O(m_out*K)) or
     "eager" (the [K, K] equality matrix, kept for A/B) — bit-identical ids.
     """
     if dedup not in ("lazy", "eager"):
@@ -169,8 +144,8 @@ def edge_select_kernel_call(
             f"edge_select: unknown dedup {dedup!r} "
             "(expected 'lazy' or 'eager')"
         )
-    n, layers, m = nbrs.shape
-    K = layers * m
+    edges = _storage.pack_edges(nbrs)
+    K, m = edges.layers * edges.m, edges.m
     F = us.shape[0]
     us = us.astype(jnp.int32)
     L = jnp.broadcast_to(jnp.asarray(L, jnp.int32), us.shape)
@@ -196,22 +171,23 @@ def edge_select_kernel_call(
 
     out = pl.pallas_call(
         functools.partial(
-            _edge_select_kernel, bf=bf, K=K, m=m, logn=logn, m_out=m_out,
-            skip_layers=skip_layers, window=min(window, bf), dedup=dedup,
+            _edge_select_kernel, bf=bf, K=K, Rw=edges.R, m=m, logn=logn,
+            m_out=m_out, skip_layers=skip_layers, window=min(window, bf),
+            dedup=dedup,
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bf, 4), lambda i: (i, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((bf, 4), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((bf, m_out), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Fp, m_out), jnp.int32),
         scratch_shapes=[
-            pltpu.VMEM((bf, K), jnp.int32),
+            pltpu.VMEM((bf, edges.R, 128), jnp.int32),
             pltpu.SemaphoreType.DMA((min(window, bf),)),
         ],
         interpret=interpret,
-    )(meta, meta, nbrs.reshape(n, K))
+    )(meta, meta, edges.rows)
     return out[:F]

@@ -26,8 +26,9 @@ bit-for-bit in f32 under identical fusion: ``||x||^2 - 2 x.q + ||q||^2``
 for l2, ``-x.q`` for ip; invalid slots return ``+inf``.
 
 VMEM residency per program is ``bb*bm*row_bytes`` for the gather scratch
-(default tiles 8x128: 0.5 MB at f32 d=128, 128 KB at int8) plus the query
-tile and, for PQ, the ``[M*256, dsub]`` codebook (128 KB at d=128, M=32).
+(default tiles 8x128: 0.5 MB at f32 d=128; 16/8-bit tables DMA 8-row
+blocks, ``kernels/rowdma.py``: 2 MB bf16, 1 MB int8) plus the query tile
+and, for PQ, the ``[M*256, dsub]`` codebook (128 KB at d=128, M=32).
 The codec tiles are autotuned separately (``kind="gather_dist_codec"``,
 ``kernels/autotune.py``). CPU/CI runs use ``interpret=True``.
 """
@@ -41,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import storage as _storage
+from repro.kernels import rowdma as _rows
 
 __all__ = ["gather_distance_kernel_call"]
 
@@ -49,8 +51,8 @@ def _gather_dist_kernel(
     q_ref,       # VMEM [bb, dp]
     ids_smem,    # SMEM [bb, bm] (DMA row indices)
     ids_vmem,    # VMEM [bb, bm] (vectorized mask)
-    *refs,       # table_ref (ANY [n, w]), [aux_ref], o_ref, xbuf, sems
-    bb, bm, metric, window, codec, dp, pq_m, pq_dsub,
+    *refs,       # table_ref (ANY), [aux_ref], o_ref, xbuf, sems
+    bb, bm, metric, window, codec, blocked, dp, pq_m, pq_dsub,
 ):
     if codec is None:
         table_ref, o_ref, xbuf, sems = refs
@@ -61,45 +63,19 @@ def _gather_dist_kernel(
     def slot_id(t):
         return ids_smem[t // bm, t % bm]
 
-    def row_copy(t):
-        return pltpu.make_async_copy(
-            table_ref.at[slot_id(t)], xbuf.at[t], sems.at[t % window]
-        )
-
-    def start(t):
-        @pl.when(slot_id(t) >= 0)
-        def _():
-            row_copy(t).start()
-
-    def wait(t):
-        @pl.when(slot_id(t) >= 0)
-        def _():
-            row_copy(t).wait()
-
-    # software-pipelined gather: keep up to `window` row DMAs in flight
-    def fill(t, carry):
-        @pl.when(t >= window)
-        def _():
-            wait(t - window)
-
-        start(t)
-        return carry
-
-    jax.lax.fori_loop(0, total, fill, 0)
-
-    def drain(t, carry):
-        wait(t)
-        return carry
-
-    jax.lax.fori_loop(max(0, total - window), total, drain, 0)
+    _rows.gather(total, window, slot_id, lambda t: [
+        pltpu.make_async_copy(_rows.src(table_ref, slot_id(t), blocked),
+                              xbuf.at[t], sems.at[t % window])])
 
     q = q_ref[...].astype(jnp.float32)       # [bb, dp]
+    ids = ids_vmem[...]
     # codec decode, in-register (§9): xbuf holds the *stored* rows
+    x = _rows.select(xbuf, _rows.to_column(ids) if blocked else None,
+                     blocked)                             # [bb*bm, w]
     if codec == "int8":
-        x = xbuf[...].astype(jnp.float32)                 # [bb*bm, dp]
-        x = x * aux_ref[...].reshape(total, 1)            # per-row scales
+        x = x * _rows.to_column(aux_ref[...])             # per-row scales
     elif codec == "pq":
-        codes = xbuf[...][:, :pq_m].astype(jnp.int32)     # [bb*bm, M]
+        codes = x[:, :pq_m].astype(jnp.int32)             # [bb*bm, M]
         sub = jax.lax.broadcasted_iota(jnp.int32, (total, pq_m), 1)
         idx = codes + sub * _storage.PQ_CENTROIDS
         x = jnp.take(aux_ref[...], idx.reshape(-1), axis=0)
@@ -108,12 +84,11 @@ def _gather_dist_kernel(
         if pad:
             x = jnp.concatenate(
                 [x, jnp.zeros((total, pad), jnp.float32)], axis=1)
-    else:
-        x = xbuf[...].astype(jnp.float32)                 # [bb*bm, dp]
     # one MXU pass against every query in the tile, then keep the diagonal
     # query<->row pairing (overcompute factor bb is tiny next to the gather)
     dots = jax.lax.dot_general(
-        x, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     ).reshape(bb, bm, bb)
     row_q = jax.lax.broadcasted_iota(jnp.int32, (bb, bm, bb), 0)
     col_q = jax.lax.broadcasted_iota(jnp.int32, (bb, bm, bb), 2)
@@ -125,8 +100,7 @@ def _gather_dist_kernel(
         xx = jnp.sum(x * x, axis=1).reshape(bb, bm)
         qq = jnp.sum(q * q, axis=1)
         out = xx - 2.0 * dot + qq[:, None]
-    valid = ids_vmem[...] >= 0
-    o_ref[...] = jnp.where(valid, out, jnp.inf)
+    o_ref[...] = jnp.where(ids >= 0, out, jnp.inf)
 
 
 @functools.partial(
@@ -151,15 +125,9 @@ def gather_distance_kernel_call(
     B, d = q.shape
     M = ids.shape[1]
     bb = min(block_b, max(8, B))
-    bm = 128 if M <= 128 else min(block_m, M)
-
-    def pad_to(a, mult, axis, value=0):
-        r = (-a.shape[axis]) % mult
-        if r == 0:
-            return a
-        widths = [(0, 0)] * a.ndim
-        widths[axis] = (0, r)
-        return jnp.pad(a, widths, constant_values=value)
+    # a narrow M is one whole tile (the full padded dim): no padded slots
+    bm = -(-M // 8) * 8 if M <= 128 else min(block_m, M)
+    pad_to = _rows.pad_to
 
     qp = pad_to(pad_to(q, bb, 0), 128, 1)
     idp = pad_to(pad_to(ids, bb, 0, value=-1), bm, 1, value=-1)
@@ -169,28 +137,25 @@ def gather_distance_kernel_call(
     codec, aux, aux_spec, pq_m, pq_dsub = None, None, None, 0, 0
     if isinstance(table, _storage.Int8Vectors):
         codec = "int8"
-        tbl = pad_to(table.codes, 128, 1)
+        tbl, blocked = _rows.row_table(table.codes)
         scales = table.scales[jnp.maximum(ids, 0)].astype(jnp.float32)
         aux = pad_to(pad_to(scales, bb, 0), bm, 1)
         aux_spec = pl.BlockSpec((bb, bm), lambda i, j: (i, j))
-        xbuf_shape = (bb * bm, tbl.shape[1])
     elif isinstance(table, _storage.PQVectors):
         codec = "pq"
         pq_m, _, pq_dsub = table.codebook.shape
-        tbl = pad_to(table.codes, 128, 1)
+        tbl, blocked = _rows.row_table(table.codes)
         aux = table.codebook.reshape(pq_m * _storage.PQ_CENTROIDS, pq_dsub)
         aux_spec = pl.BlockSpec(aux.shape, lambda i, j: (0, 0))
-        xbuf_shape = (bb * bm, tbl.shape[1])
     else:
-        tbl = pad_to(table, 128, 1)
-        xbuf_shape = (bb * bm, dp)
+        tbl, blocked = _rows.row_table(table)
 
     in_specs = [
         pl.BlockSpec((bb, dp), lambda i, j: (i, 0)),
         pl.BlockSpec((bb, bm), lambda i, j: (i, j),
                      memory_space=pltpu.SMEM),
         pl.BlockSpec((bb, bm), lambda i, j: (i, j)),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     args = [qp, idp, idp, tbl]
     if codec is not None:
@@ -200,8 +165,8 @@ def gather_distance_kernel_call(
     out = pl.pallas_call(
         functools.partial(
             _gather_dist_kernel, bb=bb, bm=bm, metric=metric,
-            window=min(window, bb * bm), codec=codec, dp=dp,
-            pq_m=pq_m, pq_dsub=pq_dsub,
+            window=min(window, bb * bm), codec=codec, blocked=blocked,
+            dp=dp, pq_m=pq_m, pq_dsub=pq_dsub,
         ),
         grid=grid,
         in_specs=in_specs,
@@ -209,7 +174,8 @@ def gather_distance_kernel_call(
         out_shape=jax.ShapeDtypeStruct((qp.shape[0], idp.shape[1]),
                                        jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM(xbuf_shape, tbl.dtype),
+            pltpu.VMEM((bb * bm, _rows.ROWS, tbl.shape[-1]) if blocked
+                       else (bb * bm, tbl.shape[1]), tbl.dtype),
             pltpu.SemaphoreType.DMA((min(window, bb * bm),)),
         ],
         interpret=interpret,

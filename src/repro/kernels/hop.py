@@ -8,35 +8,40 @@ edges land in HBM, get re-read by the bitset scatter, and the surviving ids
 get re-read again to drive the vector gather. This kernel fuses the whole
 hop so the frontier never leaves VMEM:
 
-  1. **edge gather** — per ``(bb)`` query tile the packed neighbor table
-     stays un-blocked in ``ANY``/HBM space and the kernel row-DMAs each of
-     the ``bb*W`` frontier nodes' ``K = (logn+1)*m`` edge blocks into a
-     VMEM scratch (software-pipelined, up to ``window`` copies in flight,
-     ``-1`` frontier slots skipped by predication);
+  1. **edge gather** — per ``(bb)`` query tile the neighbor table stays
+     un-blocked in ``ANY``/HBM space in its ``storage.EdgeRows`` form and
+     the kernel DMAs each of the ``bb*W`` frontier nodes' ``K =
+     (logn+1)*m`` edges (``Rw`` whole 128-lane rows) into a VMEM scratch
+     (software-pipelined, up to ``window`` copies in flight, ``-1``
+     frontier slots skipped by predication);
   2. **edge selection** — the ``segment_tree.scan_mask`` closed form
      (``ref.edge_scan_valid``) plus the *lazy* O(m_out·K) dedup: ``m_out``
      masked-argmin steps, each wiping every position holding the id it just
-     selected — no ``[K, K]`` equality matrix, so VMEM stays flat in K;
-  3. **visited test-and-set** — the query tile's ``uint32[bb, words]``
-     bitset rows live in VMEM for the whole launch; membership is
-     shift/mask arithmetic, in-row dedup is the same strictly-earlier
-     equality mask as ``core/bitset.py``, and the updated rows are written
-     back once at the end (single-bit masks scatter-add, exact OR after
-     dedup);
-  4. **vector gather + distance** — the surviving (newly-visited) ids DMA
-     their vector rows straight from the un-blocked table into a VMEM
-     scratch and one MXU matmul emits masked f32 distances, exactly the
-     ``gather_distance.py`` structure.
+     selected — no ``[K, K]`` equality matrix, so VMEM stays flat in K; the
+     selected ids then DMA to SMEM, where the next two phases index with
+     them as scalars;
+  3. **visited test-and-set** — the query tile's bitset rows (int32 words)
+     live in VMEM for the whole launch. Slots run one at a time in
+     row-major order: each reads its word as the aligned 128-word column
+     block of all ``bb`` rows, tests the bit, and sets it only when new, so
+     a repeat of an id in the same row finds its bit set — the
+     first-occurrence rule of ``core/bitset.py``. (Mosaic lowers neither a
+     general gather over the row nor an unsigned reduction.);
+  4. **vector gather + distance** — the newly-visited ids DMA their vector
+     rows (8-row blocks for 16/8-bit tables, ``kernels/rowdma.py``)
+     straight from the un-blocked table into a VMEM scratch and one MXU
+     matmul emits masked f32 distances, exactly the ``gather_distance.py``
+     structure.
 
 Semantics are ``kernels/ref.py::hop`` (select_edges -> bitset.test_and_set
 -> gather_dist): integer outputs (edges, newly-visited mask, bitset words)
 must match bit-for-bit, distances to f32 tolerance.
 
-VMEM residency per program: the vector scratch ``bb*W*m_out*d_pad`` rows
-dominate (defaults bb=4, W=4, m_out=16, d=128: 128 KB f32), plus the edge
-scratch ``bb*W*K*4`` bytes and the bitset tile ``bb*ceil(n/32)*4`` bytes —
-the bitset tile grows with n, so the autotuner (``kernels/autotune.py``)
-drops ``block_b`` for very large n. CPU/CI runs use ``interpret=True``.
+VMEM residency per program: the bitset tile ``bb*ceil(n/32)*4`` bytes,
+in and out, double-buffered, dominates at deployment n (default bb=8 at
+n = 2^20: 1 MiB each way), then the vector scratch ``bb*W*m_out*d_pad``
+rows (128 KiB f32 at W=4, m_out=16, d=128) and the edge scratch
+``bb*W*Rw*512`` bytes. CPU/CI runs use ``interpret=True``.
 """
 from __future__ import annotations
 
@@ -49,6 +54,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import storage as _storage
 from repro.kernels import ref as _ref
+from repro.kernels import rowdma as _rows
 
 __all__ = ["hop_kernel_call"]
 
@@ -59,66 +65,43 @@ _IMIN = -(2**31)
 
 
 def _hop_kernel(
-    meta_smem,   # SMEM [bb, 4*W] (u | L | R | exp) — DMA row indices
-    meta_vmem,   # VMEM [bb, 4*W] (vectorized u/L/R/exp)
+    meta_smem,   # SMEM [bb, 4*W] (u | L | R | exp) — scalar u and exp_ok
+    col_vmem,    # VMEM [bb*W, 4] (u, L, R, exp) per frontier node
     q_ref,       # VMEM [bb, dp]
-    vis_ref,     # VMEM [bb, words] (query tile's bitset rows)
-    nbrs_ref,    # ANY  [n, K]  (packed edge table, never blocked)
-    table_ref,   # ANY  [n, w]  (vector table / code table, never blocked)
-    *refs,       # [aux_ref], outputs, ebuf, xbuf, [sbuf], sems, [sems2]
-    bb, W, K, m, m_out, logn, skip_layers, metric, window,
-    codec, dp, pq_m, pq_dsub,
+    vis_ref,     # VMEM [bb, words] int32 (query tile's bitset rows)
+    nbrs_ref,    # ANY  [n*Rw, 128]  (packed edge rows, never blocked)
+    table_ref,   # ANY  vector/code table in ``rowdma.row_table`` form
+    *refs,       # [aux_ref], outputs, ebuf, esel, esel_s, gid_s, xbuf,
+                 # [sbuf], sems, [sems2]
+    bb, W, K, Rw, m, m_out, logn, skip_layers, metric, window,
+    codec, blocked, dp, pq_m, pq_dsub,
 ):
     if codec is None:
-        nbr_out, dist_out, nvalid_out, vis_out, ebuf, xbuf, sems = refs
+        (nbr_out, dist_out, nvalid_out, vis_out,
+         ebuf, esel, esel_s, gid_s, xbuf, sems) = refs
     elif codec == "int8":
         (aux_ref, nbr_out, dist_out, nvalid_out, vis_out,
-         ebuf, xbuf, sbuf, sems, sems2) = refs
+         ebuf, esel, esel_s, gid_s, xbuf, sbuf, sems, sems2) = refs
     else:  # pq
         (aux_ref, nbr_out, dist_out, nvalid_out, vis_out,
-         ebuf, xbuf, sems) = refs
+         ebuf, esel, esel_s, gid_s, xbuf, sems) = refs
     WM = W * m_out
     F = bb * W
+    T = bb * WM
 
-    # -- 1. pipelined edge-block gather (one row DMA per frontier node) -----
+    # -- 1. pipelined edge-block gather (one Rw-row DMA per frontier node) --
     def edge_u(t):
         return meta_smem[t // W, t % W]
 
-    def edge_copy(t):
-        return pltpu.make_async_copy(
-            nbrs_ref.at[edge_u(t)], ebuf.at[t], sems.at[t % window]
-        )
-
-    def edge_fill(t, carry):
-        @pl.when(t >= window)
-        def _():
-            @pl.when(edge_u(t - window) >= 0)
-            def _():
-                edge_copy(t - window).wait()
-
-        @pl.when(edge_u(t) >= 0)
-        def _():
-            edge_copy(t).start()
-
-        return carry
-
-    jax.lax.fori_loop(0, F, edge_fill, 0)
-
-    def edge_drain(t, carry):
-        @pl.when(edge_u(t) >= 0)
-        def _():
-            edge_copy(t).wait()
-
-        return carry
-
-    jax.lax.fori_loop(max(0, F - window), F, edge_drain, 0)
+    _rows.gather(F, window, edge_u, lambda t: [pltpu.make_async_copy(
+        nbrs_ref.at[pl.ds(edge_u(t) * Rw, Rw)], ebuf.at[t],
+        sems.at[t % window])])
 
     # -- 2. edge selection: scan-mask validity + lazy O(m_out*K) dedup ------
-    us = meta_vmem[:, 0 * W:1 * W].reshape(F, 1)
-    L = meta_vmem[:, 1 * W:2 * W].reshape(F, 1)
-    R = meta_vmem[:, 2 * W:3 * W].reshape(F, 1)
-    exp_ok = meta_vmem[:, 3 * W:4 * W] != 0               # [bb, W]
-    flat = ebuf[...]                                      # [F, K]
+    col = col_vmem[...]
+    us, L, R = col[:, 0:1], col[:, 1:2], col[:, 2:3]      # [F, 1]
+    flat = jnp.concatenate(
+        [ebuf[:, c, :] for c in range(Rw)], axis=1)[:, :K]  # [F, K]
 
     lay = jax.lax.broadcasted_iota(jnp.int32, (F, K), 1) // m
     valid = _ref.edge_scan_valid(
@@ -139,101 +122,94 @@ def _hop_kernel(
         outs.append(out_t)
         taken = (flat == out_t) & (prio < _BIG)
         prio = jnp.where(sel | taken, _BIG, prio)
-    edges = jnp.concatenate(outs, axis=1).reshape(bb, WM)
-    nbr_out[...] = edges
+    # the selected ids go to SMEM: the visited test-and-set and the vector
+    # DMAs below index with them as scalars
+    esel[...] = jnp.concatenate(outs, axis=1)             # [F, m_out]
+    to_smem = pltpu.make_async_copy(esel, esel_s, sems.at[0])
+    to_smem.start()
+    to_smem.wait()
 
     # -- 3. visited test-and-set, bitset rows resident in VMEM --------------
-    pre_valid = edges >= 0
-    pre_valid &= jnp.repeat(exp_ok, m_out, axis=1)        # [bb, WM]
-    safe = jnp.maximum(edges, 0)
-    word_idx = safe >> 5
-    shift = (safe & 31).astype(jnp.uint32)
-    vis = vis_ref[...]                                    # [bb, words]
-    word = jnp.take_along_axis(vis, word_idx, axis=1)
-    seen = ((word >> shift) & jnp.uint32(1)) == 1
-    seen &= pre_valid
-    # first occurrence wins within a row (same id from two expansions)
-    j_pos = jax.lax.broadcasted_iota(jnp.int32, (bb, WM, WM), 1)
-    i_pos = jax.lax.broadcasted_iota(jnp.int32, (bb, WM, WM), 2)
-    eq = (safe[:, :, None] == safe[:, None, :]) \
-        & pre_valid[:, :, None] & pre_valid[:, None, :]
-    dup = jnp.any(eq & (i_pos < j_pos), axis=2)           # [bb, WM]
-    new = pre_valid & ~seen & ~dup
-    nvalid = pre_valid & ~(seen | dup)
-    # single-bit masks are unique (row, word, bit) after dedup: add == OR
-    mask = jnp.where(new, jnp.uint32(1) << shift, jnp.uint32(0))
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bb, WM), 0)
-    vis_out[...] = vis.at[rows, word_idx].add(mask)
-    nvalid_out[...] = nvalid.astype(jnp.int32)
+    # One slot at a time, in row-major slot order, so the first occurrence
+    # of an id within a row wins (the second finds its bit set). A slot's
+    # word is read as the aligned 128-word column block of all bb rows.
+    vis_out[...] = vis_ref[...]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (bb, 128), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bb, 128), 1)
+    slot = (jax.lax.broadcasted_iota(jnp.int32, (bb, WM), 0) * WM
+            + jax.lax.broadcasted_iota(jnp.int32, (bb, WM), 1))
+
+    def visit(t, carry):
+        edges, nvalid = carry
+        f, j = t // m_out, t % m_out
+        b, w = f // W, f % W
+        idv = esel_s[f, j]
+        ok = (idv >= 0) & (meta_smem[b, 3 * W + w] != 0)
+        safe = jnp.maximum(idv, 0)
+        word = safe >> 5
+        base = pl.multiple_of((word >> 7) * 128, 128)
+        blk = vis_out[:, pl.ds(base, 128)]                # [bb, 128]
+        hit = (sub == b) & (lane == (word & 127))
+        bit = (jnp.sum(jnp.where(hit, blk, 0)) >> (safe & 31)) & 1
+        new = ok & (bit == 0)
+        gid_s[t] = jnp.where(new, idv, -1)
+
+        @pl.when(new)
+        def _():
+            vis_out[:, pl.ds(base, 128)] = blk | jnp.where(
+                hit, jnp.int32(1) << (safe & 31), 0)
+
+        edges = jnp.where(slot == t, idv, edges)
+        nvalid = jnp.where(slot == t, new.astype(jnp.int32), nvalid)
+        return edges, nvalid
+
+    zero = jnp.zeros((bb, WM), jnp.int32)
+    edges, nvalid = jax.lax.fori_loop(0, T, visit, (zero, zero))
+    nbr_out[...] = edges
+    nvalid_out[...] = nvalid
 
     # -- 4. pipelined vector gather for the newly-visited ids ---------------
-    gids = jnp.where(nvalid, edges, -1).reshape(bb * WM)
-
     def vec_id(t):
-        return gids[t]
+        return gid_s[t]
 
-    def vec_copy(t):
-        return pltpu.make_async_copy(
-            table_ref.at[vec_id(t)], xbuf.at[t], sems.at[t % window]
-        )
+    def vec_copies(t):
+        cps = [pltpu.make_async_copy(
+            _rows.src(table_ref, vec_id(t), blocked), xbuf.at[t],
+            sems.at[t % window])]
+        if codec == "int8":
+            # ids are discovered in-kernel, so the per-row scale rides a
+            # parallel DMA of its 128-scale row (aux_ref is ANY [n/128, 128])
+            cps.append(pltpu.make_async_copy(
+                aux_ref.at[vec_id(t) >> 7], sbuf.at[t],
+                sems2.at[t % window]))
+        return cps
 
-    def scale_copy(t):
-        # int8 only: ids are discovered in-kernel, so the per-row scales
-        # must ride a parallel DMA (aux_ref is ANY [n, 1] f32)
-        return pltpu.make_async_copy(
-            aux_ref.at[vec_id(t)], sbuf.at[t], sems2.at[t % window]
-        )
-
-    def vec_fill(t, carry):
-        @pl.when(t >= window)
-        def _():
-            @pl.when(vec_id(t - window) >= 0)
-            def _():
-                vec_copy(t - window).wait()
-                if codec == "int8":
-                    scale_copy(t - window).wait()
-
-        @pl.when(vec_id(t) >= 0)
-        def _():
-            vec_copy(t).start()
-            if codec == "int8":
-                scale_copy(t).start()
-
-        return carry
-
-    jax.lax.fori_loop(0, bb * WM, vec_fill, 0)
-
-    def vec_drain(t, carry):
-        @pl.when(vec_id(t) >= 0)
-        def _():
-            vec_copy(t).wait()
-            if codec == "int8":
-                scale_copy(t).wait()
-
-        return carry
-
-    jax.lax.fori_loop(max(0, bb * WM - window), bb * WM, vec_drain, 0)
+    _rows.gather(T, window, vec_id, vec_copies)
 
     # -- distance: one MXU pass, keep the diagonal query<->row pairing ------
     q = q_ref[...].astype(jnp.float32)                    # [bb, dp]
+    gcol = (_rows.to_column(jnp.where(nvalid != 0, edges, -1))
+            if blocked or codec == "int8" else None)      # [T, 1]
     # codec decode, in-register (DESIGN.md §9): xbuf holds the stored rows
+    x = _rows.select(xbuf, gcol, blocked)                 # [T, w]
     if codec == "int8":
-        x = xbuf[...].astype(jnp.float32)                 # [bb*WM, w]
-        x = x * sbuf[...].reshape(bb * WM, 1)             # per-row scales
+        lane_s = jax.lax.broadcasted_iota(jnp.int32, (T, 128), 1)
+        scale = jnp.sum(jnp.where(lane_s == (gcol & 127), sbuf[...], 0.0),
+                        axis=1, keepdims=True)            # [T, 1]
+        x = x * scale
     elif codec == "pq":
-        codes = xbuf[...][:, :pq_m].astype(jnp.int32)     # [bb*WM, M]
-        sub = jax.lax.broadcasted_iota(jnp.int32, (bb * WM, pq_m), 1)
-        idx = codes + sub * _storage.PQ_CENTROIDS
+        codes = x[:, :pq_m].astype(jnp.int32)             # [T, M]
+        sub_m = jax.lax.broadcasted_iota(jnp.int32, (T, pq_m), 1)
+        idx = codes + sub_m * _storage.PQ_CENTROIDS
         x = jnp.take(aux_ref[...], idx.reshape(-1), axis=0)
-        x = x.reshape(bb * WM, pq_m * pq_dsub)
+        x = x.reshape(T, pq_m * pq_dsub)
         pad = dp - pq_m * pq_dsub
         if pad:
             x = jnp.concatenate(
-                [x, jnp.zeros((bb * WM, pad), jnp.float32)], axis=1)
-    else:
-        x = xbuf[...].astype(jnp.float32)                 # [bb*WM, dp]
+                [x, jnp.zeros((T, pad), jnp.float32)], axis=1)
     dots = jax.lax.dot_general(
-        x, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     ).reshape(bb, WM, bb)
     row_q = jax.lax.broadcasted_iota(jnp.int32, (bb, WM, bb), 0)
     col_q = jax.lax.broadcasted_iota(jnp.int32, (bb, WM, bb), 2)
@@ -244,7 +220,7 @@ def _hop_kernel(
         xx = jnp.sum(x * x, axis=1).reshape(bb, WM)
         qq = jnp.sum(q * q, axis=1)
         out = xx - 2.0 * dot + qq[:, None]
-    dist_out[...] = jnp.where(nvalid, out, jnp.inf)
+    dist_out[...] = jnp.where(nvalid != 0, out, jnp.inf)
 
 
 @functools.partial(
@@ -254,38 +230,36 @@ def _hop_kernel(
 )
 def hop_kernel_call(
     q, table, nbrs, u, L, R, visited, exp_ok, *, logn, m_out,
-    skip_layers=True, metric="l2", block_b=4, window=8, interpret=False,
+    skip_layers=True, metric="l2", block_b=8, window=8, interpret=False,
 ):
     """One fused whole-hop launch (DESIGN.md §3). See ``kernels/ref.py::hop``
     for the semantic contract and shapes: q f32[B, d], table ([n, d] float /
-    Int8Vectors / PQVectors), nbrs int32[n, layers, m] (pre-decoded), u
+    Int8Vectors / PQVectors), nbrs ([n, layers, m] any codec, or the
+    ``storage.EdgeRows`` row-DMA form the serving path uploads), u
     int32[B, W], L/R int32[B*W], visited uint32[B, words], exp_ok
     bool[B, W]. Returns ``(nbr, ndist, nvalid, visited')``.
 
-    Pads B to the ``block_b`` tile multiple and the stored row width to the
-    128 lane width internally (zero columns are exact for both metrics);
-    the edge and vector tables pass flattened/un-blocked so every gather is
-    one contiguous row DMA. Codec decode happens in-register after the DMA
-    (DESIGN.md §9). Because the gathered ids are *discovered inside* the
-    kernel, the int8 per-row scales cannot be pre-gathered like
-    ``gather_distance.py`` does — they ride as an ``ANY [n, 1]`` f32 input
-    with a parallel per-row DMA (second semaphore array) into a
-    ``[bb*WM, 1]`` scratch; the PQ codebook is a VMEM-resident input.
+    Pads B to the ``block_b`` tile multiple (at least 8 rows, the sublane
+    tile), the bitset rows to whole 128-word columns and the stored row
+    width to the 128 lane width (zero columns are exact for both metrics).
+    A ``[n, layers, m]`` edge table is repacked into whole 128-lane rows
+    per node (``storage.pack_edges``, a full-table copy: pass ``EdgeRows``
+    to skip it) and the vector table viewed in its row-DMA form
+    (``rowdma.row_table``), so every gather is one contiguous DMA. Codec
+    decode happens in-register after the DMA (DESIGN.md §9). Because the
+    gathered ids are *discovered inside* the kernel, the int8 per-row
+    scales cannot be pre-gathered like ``gather_distance.py`` does — they
+    ride as an ``ANY [n/128, 128]`` f32 input with a parallel DMA of each
+    id's 128-scale row (second semaphore array); the PQ codebook is a
+    VMEM-resident input.
     """
     B, d = q.shape
-    n, layers, m = nbrs.shape
-    K = layers * m
+    edges = _storage.pack_edges(nbrs)
+    K, Rw = edges.layers * edges.m, edges.R
     W = u.shape[1]
     words = visited.shape[1]
-    bb = max(1, min(block_b, B))
-
-    def pad_to(a, mult, axis, value=0):
-        r = (-a.shape[axis]) % mult
-        if r == 0:
-            return a
-        widths = [(0, 0)] * a.ndim
-        widths[axis] = (0, r)
-        return jnp.pad(a, widths, constant_values=value)
+    bb = min(block_b, max(8, B))
+    pad_to = _rows.pad_to
 
     meta = jnp.concatenate(
         [
@@ -297,10 +271,13 @@ def hop_kernel_call(
         axis=1,
     )                                                     # [B, 4W]
     meta = pad_to(meta, bb, 0, value=-1)
-    qp = pad_to(pad_to(q, bb, 0), 128, 1)
-    vp = pad_to(visited, bb, 0)
-    dp = qp.shape[1]
     Bp = meta.shape[0]
+    col = meta.reshape(Bp, 4, W).transpose(0, 2, 1).reshape(Bp * W, 4)
+    qp = pad_to(pad_to(q, bb, 0), 128, 1)
+    vp = pad_to(pad_to(jax.lax.bitcast_convert_type(visited, jnp.int32),
+                       bb, 0), 128, 1)
+    wp = vp.shape[1]
+    dp = qp.shape[1]
     grid = (Bp // bb,)
     WM = W * m_out
     win = max(1, min(window, bb * W))
@@ -308,47 +285,53 @@ def hop_kernel_call(
     codec, aux, aux_spec, pq_m, pq_dsub = None, None, None, 0, 0
     if isinstance(table, _storage.Int8Vectors):
         codec = "int8"
-        tp = pad_to(table.codes, 128, 1)
-        aux = table.scales.astype(jnp.float32).reshape(n, 1)
-        aux_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        tp, blocked = _rows.row_table(table.codes)
+        aux = pad_to(table.scales.astype(jnp.float32), 128, 0)
+        aux = aux.reshape(-1, 128)
+        aux_spec = pl.BlockSpec(memory_space=pl.ANY)
     elif isinstance(table, _storage.PQVectors):
         codec = "pq"
         pq_m, _, pq_dsub = table.codebook.shape
-        tp = pad_to(table.codes, 128, 1)
+        tp, blocked = _rows.row_table(table.codes)
         aux = table.codebook.reshape(pq_m * _storage.PQ_CENTROIDS, pq_dsub)
         aux_spec = pl.BlockSpec(aux.shape, lambda i: (0, 0))
     else:
-        tp = pad_to(table, 128, 1)
+        tp, blocked = _rows.row_table(table)
 
     in_specs = [
         pl.BlockSpec((bb, 4 * W), lambda i: (i, 0),
                      memory_space=pltpu.SMEM),
-        pl.BlockSpec((bb, 4 * W), lambda i: (i, 0)),
+        pl.BlockSpec((bb * W, 4), lambda i: (i, 0)),
         pl.BlockSpec((bb, dp), lambda i: (i, 0)),
-        pl.BlockSpec((bb, words), lambda i: (i, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec((bb, wp), lambda i: (i, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
-    args = [meta, meta, qp, vp, nbrs.reshape(n, K), tp]
+    args = [meta, col, qp, vp, edges.rows, tp]
     if codec is not None:
         in_specs.append(aux_spec)
         args.append(aux)
 
     scratch_shapes = [
-        pltpu.VMEM((bb * W, K), jnp.int32),
-        pltpu.VMEM((bb * WM, tp.shape[1]), tp.dtype),
+        pltpu.VMEM((bb * W, Rw, 128), jnp.int32),
+        pltpu.VMEM((bb * W, m_out), jnp.int32),
+        pltpu.SMEM((bb * W, m_out), jnp.int32),
+        pltpu.SMEM((bb * WM,), jnp.int32),
+        pltpu.VMEM((bb * WM, _rows.ROWS, tp.shape[-1]) if blocked
+                   else (bb * WM, tp.shape[1]), tp.dtype),
     ]
     if codec == "int8":
-        scratch_shapes.append(pltpu.VMEM((bb * WM, 1), jnp.float32))
+        scratch_shapes.append(pltpu.VMEM((bb * WM, 128), jnp.float32))
     scratch_shapes.append(pltpu.SemaphoreType.DMA((win,)))
     if codec == "int8":
         scratch_shapes.append(pltpu.SemaphoreType.DMA((win,)))
 
     nbr, dist, nvalid, vis = pl.pallas_call(
         functools.partial(
-            _hop_kernel, bb=bb, W=W, K=K, m=m, m_out=m_out, logn=logn,
-            skip_layers=skip_layers, metric=metric, window=win,
-            codec=codec, dp=dp, pq_m=pq_m, pq_dsub=pq_dsub,
+            _hop_kernel, bb=bb, W=W, K=K, Rw=Rw, m=edges.m,
+            m_out=m_out, logn=logn, skip_layers=skip_layers, metric=metric,
+            window=win, codec=codec, blocked=blocked, dp=dp, pq_m=pq_m,
+            pq_dsub=pq_dsub,
         ),
         grid=grid,
         in_specs=in_specs,
@@ -356,15 +339,16 @@ def hop_kernel_call(
             pl.BlockSpec((bb, WM), lambda i: (i, 0)),
             pl.BlockSpec((bb, WM), lambda i: (i, 0)),
             pl.BlockSpec((bb, WM), lambda i: (i, 0)),
-            pl.BlockSpec((bb, words), lambda i: (i, 0)),
+            pl.BlockSpec((bb, wp), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Bp, WM), jnp.int32),
             jax.ShapeDtypeStruct((Bp, WM), jnp.float32),
             jax.ShapeDtypeStruct((Bp, WM), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, words), jnp.uint32),
+            jax.ShapeDtypeStruct((Bp, wp), jnp.int32),
         ],
         scratch_shapes=scratch_shapes,
         interpret=interpret,
     )(*args)
-    return nbr[:B], dist[:B], nvalid[:B] != 0, vis[:B]
+    vis = jax.lax.bitcast_convert_type(vis[:B, :words], jnp.uint32)
+    return nbr[:B], dist[:B], nvalid[:B] != 0, vis

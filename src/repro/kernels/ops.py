@@ -146,21 +146,22 @@ def select_edges(nbrs, us, L, R, *, logn, m_out, skip_layers=True,
     if impl == "auto":
         impl = default_impl("edge")
     _check_impl("select_edges", impl, {"pallas", "xla", "argsort"})
+    if impl == "pallas":
+        # the kernel reads the EdgeRows form (packing decodes any codec)
+        return _edge_select.edge_select_kernel_call(
+            nbrs, us, L, R, logn=logn, m_out=m_out, skip_layers=skip_layers,
+            interpret=_interpret(),
+            **{**_autotune.get_pick("edge_select"), **block_kw},
+        )
     # compact neighbor tables (int16 ids, -1 sentinel) decode here so every
-    # backend sees int32; trace-time no-op for already-wide tables
+    # jnp backend sees int32; trace-time no-op for already-wide tables
     nbrs = _storage.decode_neighbors(nbrs)
     if impl == "xla":
         return _ref.select_edges(
             nbrs, us, L, R, logn=logn, m_out=m_out, skip_layers=skip_layers
         )
-    if impl == "argsort":
-        return _legacy_edge_select.select_edges_batch(
-            nbrs, us, L, R, logn=logn, m_out=m_out, skip_layers=skip_layers
-        )
-    return _edge_select.edge_select_kernel_call(
-        nbrs, us, L, R, logn=logn, m_out=m_out, skip_layers=skip_layers,
-        interpret=_interpret(),
-        **{**_autotune.get_pick("edge_select"), **block_kw},
+    return _legacy_edge_select.select_edges_batch(
+        nbrs, us, L, R, logn=logn, m_out=m_out, skip_layers=skip_layers
     )
 
 
@@ -252,8 +253,9 @@ def hop(q, table, nbrs, u, L, R, visited, exp_ok, *, logn, m_out,
 
     Shapes: q f32[B, d], table ([n, d] float or a codec struct —
     ``storage.Int8Vectors`` / ``storage.PQVectors``, decoded in-register by
-    the megakernel per DESIGN.md §9), nbrs [n, layers, m] (compact
-    int16/split decodes here), u int32[B, W], L/R int32[B*W], visited
+    the megakernel per DESIGN.md §9), nbrs [n, layers, m] in any neighbor
+    codec or ``storage.EdgeRows`` (the Pallas kernels read that form; the
+    jnp paths decode), u int32[B, W], L/R int32[B*W], visited
     uint32[B, words], exp_ok bool[B, W] -> (nbr i32[B, W*m_out], ndist
     f32[B, W*m_out], nvalid bool[B, W*m_out], visited' uint32[B, words]).
     """
@@ -295,7 +297,6 @@ def hop(q, table, nbrs, u, L, R, visited, exp_ok, *, logn, m_out,
         # bit-exactness) routes through the composed path even when
         # REPRO_HOP_IMPL forces "pallas"
         impl = "composed"
-    nbrs = _storage.decode_neighbors(nbrs)
     if impl == "composed":
         B, W = u.shape
         nbr = select_edges(
@@ -312,8 +313,9 @@ def hop(q, table, nbrs, u, L, R, visited, exp_ok, *, logn, m_out,
         return nbr, ndist, nvalid, visited
     if impl == "xla":
         return _ref.hop(
-            q, table, nbrs, u, L, R, visited, exp_ok, logn=logn,
-            m_out=m_out, skip_layers=skip_layers, metric=metric,
+            q, table, _storage.decode_neighbors(nbrs), u, L, R, visited,
+            exp_ok, logn=logn, m_out=m_out, skip_layers=skip_layers,
+            metric=metric,
         )
     return _hop.hop_kernel_call(
         q, table, nbrs, u, L, R, visited, exp_ok, logn=logn, m_out=m_out,

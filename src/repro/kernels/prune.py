@@ -22,9 +22,12 @@ Ids match ``kernels/ref.py::prune`` (the lazy jnp contract) and
 compare f32 distances built from the same ``xx_i - 2 x_i.x_j + xx_j``
 expansion, so parity holds under identical fusion.
 
+Duplicate candidate ids are wiped lazily inside the sweeps (no
+``[bb, C, C]`` matrix: Mosaic cannot lower its rank-3 bool broadcast).
 VMEM residency per program: the gather scratch ``bb*C*d_pad*4`` bytes
-(default ``bb=8``, C=128, d=128: 0.5 MB) plus the ``[bb, C, C]`` dedup
-masks (0.5 MB as i32 at C=128); lower ``block_b`` for very large ``C*d``.
+(default ``bb=8``, C=128, d=128: 0.5 MB) plus the ``[bb*C, C]`` row repeat
+that turns each sweep's one-hot selection into a column (0.5 MB as f32 at
+C=128); lower ``block_b`` for very large ``C*d``.
 CPU/CI runs use ``interpret=True``.
 """
 from __future__ import annotations
@@ -37,6 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import storage as _storage
+from repro.kernels import rowdma as _rows
 
 __all__ = ["prune_kernel_call"]
 
@@ -45,8 +49,8 @@ def _prune_kernel(
     ids_smem,    # SMEM [bb, C] (DMA row indices)
     ids_vmem,    # VMEM [bb, C] (vectorized ids)
     du_ref,      # VMEM [bb, C] squared distances to u
-    *refs,       # table_ref (ANY [n, w]), [aux_ref], o_ref, xbuf, sems
-    bb, C, m, alpha, fill, window, codec, pq_m, pq_dsub,
+    *refs,       # table_ref (ANY), [aux_ref], o_ref, xbuf, sems
+    bb, C, m, alpha, fill, window, codec, blocked, pq_m, pq_dsub,
 ):
     if codec is None:
         table_ref, o_ref, xbuf, sems = refs
@@ -58,70 +62,32 @@ def _prune_kernel(
     def slot_id(t):
         return ids_smem[t // C, t % C]
 
-    def row_copy(t):
-        return pltpu.make_async_copy(
-            table_ref.at[slot_id(t)], xbuf.at[t], sems.at[t % window]
-        )
-
-    def start(t):
-        @pl.when(slot_id(t) >= 0)
-        def _():
-            row_copy(t).start()
-
-    def wait(t):
-        @pl.when(slot_id(t) >= 0)
-        def _():
-            row_copy(t).wait()
-
-    # software-pipelined gather: keep up to `window` row DMAs in flight
-    def fill_loop(t, carry):
-        @pl.when(t >= window)
-        def _():
-            wait(t - window)
-
-        start(t)
-        return carry
-
-    jax.lax.fori_loop(0, total, fill_loop, 0)
-
-    def drain(t, carry):
-        wait(t)
-        return carry
-
-    jax.lax.fori_loop(max(0, total - window), total, drain, 0)
+    _rows.gather(total, window, slot_id, lambda t: [
+        pltpu.make_async_copy(_rows.src(table_ref, slot_id(t), blocked),
+                              xbuf.at[t], sems.at[t % window])])
 
     ids = ids_vmem[...]                                   # [bb, C]
     du = du_ref[...]                                      # [bb, C]
     # codec decode, in-register (DESIGN.md §9): xbuf holds the stored rows
+    x = _rows.select(xbuf, _rows.to_column(ids) if blocked else None,
+                     blocked)                             # [bb*C, w]
     if codec == "int8":
-        x = xbuf[...].astype(jnp.float32)
-        x = x * aux_ref[...].reshape(total, 1)            # per-row scales
+        x = x * _rows.to_column(aux_ref[...])             # per-row scales
     elif codec == "pq":
-        codes = xbuf[...][:, :pq_m].astype(jnp.int32)
+        codes = x[:, :pq_m].astype(jnp.int32)
         sub = jax.lax.broadcasted_iota(jnp.int32, (total, pq_m), 1)
         idx = codes + sub * _storage.PQ_CENTROIDS
         x = jnp.take(aux_ref[...], idx.reshape(-1), axis=0)
         x = x.reshape(total, pq_m * pq_dsub)
-    else:
-        x = xbuf[...].astype(jnp.float32)                 # [bb*C, d]
     xx = jnp.sum(x * x, axis=1).reshape(bb, C)            # [bb, C]
     pos = jax.lax.broadcasted_iota(jnp.int32, (bb, C), 1)
     valid = (ids >= 0) & jnp.isfinite(du)
 
-    # first-occurrence dedup in (du, position) order: same winner as the
-    # oracle's stable distance sort followed by keep-first-id
-    pos_i = jax.lax.broadcasted_iota(jnp.int32, (bb, C, C), 1)
-    pos_j = jax.lax.broadcasted_iota(jnp.int32, (bb, C, C), 2)
-    same = ids[:, :, None] == ids[:, None, :]
-    earlier = (du[:, :, None] < du[:, None, :]) | (
-        (du[:, :, None] == du[:, None, :]) & (pos_i < pos_j)
-    )
-    dup = jnp.any(
-        same & earlier & valid[:, :, None] & valid[:, None, :], axis=1
-    )
-    valid &= ~dup
-
     # -- keep-set recurrence + fill, one masked-argmin sweep per slot -------
+    # Duplicate ids are wiped lazily: selecting an id marks every position
+    # holding it taken. Duplicates share a vector, hence du, cc and class,
+    # so the (du, position)-first copy is always the one selected — the
+    # oracle's stable distance sort followed by keep-first-id.
     supp = jnp.zeros((bb, C), bool)
     taken = jnp.zeros((bb, C), bool)
     outs = []
@@ -144,17 +110,20 @@ def _prune_kernel(
             jnp.where(onehot, ids, jnp.iinfo(jnp.int32).min),
             axis=1, keepdims=True,
         )
-        outs.append(jnp.where(has, out_t, jnp.int32(-1)))
+        out_t = jnp.where(has, out_t, jnp.int32(-1))
+        outs.append(out_t)
         # the selected keep's cc column, computed lazily: one MXU pass of
         # the whole gathered chunk against the selected vector (overcompute
         # factor bb, the gather_distance diagonal trick)
+        sel_col = _rows.to_column(jnp.where(onehot, 1.0, 0.0))  # [bb*C, 1]
         vsel = jnp.sum(
-            jnp.where(onehot[:, :, None], x.reshape(bb, C, -1), 0.0), axis=1
+            jnp.where(sel_col > 0, x, 0.0).reshape(bb, C, -1), axis=1
         )                                                 # [bb, d]
         xx_sel = jnp.sum(jnp.where(onehot, xx, 0.0), axis=1, keepdims=True)
         dots = jax.lax.dot_general(
             x, vsel, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         ).reshape(bb, C, bb)
         row_q = jax.lax.broadcasted_iota(jnp.int32, (bb, C, bb), 0)
         col_q = jax.lax.broadcasted_iota(jnp.int32, (bb, C, bb), 2)
@@ -162,8 +131,12 @@ def _prune_kernel(
         cc = jnp.maximum(xx - 2.0 * xy + xx_sel, 0.0)
         is_keep = has & (cmin == 0)
         supp |= is_keep & (alpha * cc < du)
-        taken |= onehot
-    o_ref[...] = jnp.concatenate(outs, axis=1)
+        taken |= onehot | ((ids == out_t) & valid)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bb, m), 1)
+    res = jnp.full((bb, m), -1, jnp.int32)
+    for i, out_t in enumerate(outs):
+        res = jnp.where(lane == i, out_t, res)
+    o_ref[...] = res
 
 
 @functools.partial(
@@ -186,18 +159,14 @@ def prune_kernel_call(
     DMA. Codec tables decode in VMEM registers after the DMA (DESIGN.md §9),
     exactly like the gather-distance kernel.
     """
-    B, C = cand_ids.shape
+    B, _ = cand_ids.shape
     bb = min(block_b, max(8, B))
-    ids = cand_ids.astype(jnp.int32)
-    du = cand_dists.astype(jnp.float32)
-
-    def pad_to(a, mult, axis, value=0):
-        r = (-a.shape[axis]) % mult
-        if r == 0:
-            return a
-        widths = [(0, 0)] * a.ndim
-        widths[axis] = (0, r)
-        return jnp.pad(a, widths, constant_values=value)
+    pad_to = _rows.pad_to
+    # C to a sublane multiple: padded slots are invalid (-1 / inf), so they
+    # are never selected, and the [bb*C] <-> [bb, C] reshapes stay free
+    ids = pad_to(cand_ids.astype(jnp.int32), 8, 1, value=-1)
+    du = pad_to(cand_dists.astype(jnp.float32), 8, 1, value=jnp.inf)
+    C = ids.shape[1]
 
     idp = pad_to(ids, bb, 0, value=-1)
     dup_ = pad_to(du, bb, 0, value=jnp.inf)
@@ -206,25 +175,25 @@ def prune_kernel_call(
     codec, aux, aux_spec, pq_m, pq_dsub = None, None, None, 0, 0
     if isinstance(table, _storage.Int8Vectors):
         codec = "int8"
-        tp = pad_to(table.codes, 128, 1)
+        tp, blocked = _rows.row_table(table.codes)
         scales = table.scales[jnp.maximum(ids, 0)].astype(jnp.float32)
         aux = pad_to(scales, bb, 0)
         aux_spec = pl.BlockSpec((bb, C), lambda i: (i, 0))
     elif isinstance(table, _storage.PQVectors):
         codec = "pq"
         pq_m, _, pq_dsub = table.codebook.shape
-        tp = pad_to(table.codes, 128, 1)
+        tp, blocked = _rows.row_table(table.codes)
         aux = table.codebook.reshape(pq_m * 256, pq_dsub)
         aux_spec = pl.BlockSpec(aux.shape, lambda i: (0, 0))
     else:
-        tp = pad_to(table, 128, 1)
+        tp, blocked = _rows.row_table(table)
 
     in_specs = [
         pl.BlockSpec((bb, C), lambda i: (i, 0),
                      memory_space=pltpu.SMEM),
         pl.BlockSpec((bb, C), lambda i: (i, 0)),
         pl.BlockSpec((bb, C), lambda i: (i, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     args = [idp, idp, dup_, tp]
     if codec is not None:
@@ -234,15 +203,16 @@ def prune_kernel_call(
     out = pl.pallas_call(
         functools.partial(
             _prune_kernel, bb=bb, C=C, m=m, alpha=alpha, fill=fill,
-            window=min(window, bb * C), codec=codec, pq_m=pq_m,
-            pq_dsub=pq_dsub,
+            window=min(window, bb * C), codec=codec, blocked=blocked,
+            pq_m=pq_m, pq_dsub=pq_dsub,
         ),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bb, m), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((idp.shape[0], m), jnp.int32),
         scratch_shapes=[
-            pltpu.VMEM((bb * C, tp.shape[1]), tp.dtype),
+            pltpu.VMEM((bb * C, _rows.ROWS, tp.shape[-1]) if blocked
+                       else (bb * C, tp.shape[1]), tp.dtype),
             pltpu.SemaphoreType.DMA((min(window, bb * C),)),
         ],
         interpret=interpret,

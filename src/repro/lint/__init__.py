@@ -143,15 +143,16 @@ class Context:
                 )
             )
         # the paper-system public surface: the index/search API, the
-        # serving stack, the baselines/multiattr/distributed workloads and
-        # the linter itself. Deliberately NOT the dryrun/train harness —
+        # serving stack, the baselines/multiattr/distributed workloads, the
+        # compile-cache placement every entry script calls, and the linter
+        # itself. Deliberately NOT the dryrun/train harness —
         # that is the fence around the seed-vestigial model zoo (R6).
         self.entry_points = entry_points or (
             "repro.core", "repro.core.index", "repro.core.baselines",
             "repro.core.multiattr", "repro.core.distributed",
             "repro.serve.engine", "repro.serve.loop", "repro.serve.executor",
-            "repro.kernels.ops", "repro.compressio", "repro.lint",
-            "repro.lint.__main__",
+            "repro.kernels.ops", "repro.compressio", "repro.compile_cache",
+            "repro.lint", "repro.lint.__main__",
         )
         self._source: dict[str, str] = {}
         self._tree: dict[str, ast.Module] = {}

@@ -73,41 +73,47 @@ def _pq(d: int) -> tuple[int, int]:
     return max(1, d // dsub), dsub
 
 
+# Gathered-row scratch is costed for a one-lane-tile 32-bit table, one row
+# per slot (``blocked=False``); a 16/8-bit table DMAs 8-row blocks
+# (``kernels/rowdma.py``), 2-4x these bytes.
+_ROWDMA = {"blocked": False, "_rows": NS(ROWS=8)}
+
+
 def _hop_env(p, c):
     layers = _layers(p["n"])
     K = layers * p["m"]
-    bb = max(1, min(c["block_b"], p["B"]))
+    bb = min(c["block_b"], max(8, p["B"]))
     WM = p["W"] * p["m_out"]
     dp = _ceil_to(p["d"], 128)
     pq_m, dsub = _pq(p["d"])
     return {
         "bb": bb, "W": p["W"], "K": K, "WM": WM, "dp": dp,
-        "words": -(-p["n"] // 32),
+        "wp": _ceil_to(-(-p["n"] // 32), 128), "Rw": -(-K // 128),
         "win": max(1, min(c["window"], bb * p["W"])),
         "m_out": p["m_out"], "window": c["window"],
         "tp": NS(shape=(p["n"], dp), dtype=4),
         "aux": NS(shape=(pq_m * 256, dsub)),
-        "jnp": _DTYPES,
+        "jnp": _DTYPES, **_ROWDMA,
     }
 
 
 def _gather_env(p, c):
     bb = min(c["block_b"], max(8, p["B"]))
-    bm = 128 if p["M"] <= 128 else min(c["block_m"], p["M"])
+    bm = -(-p["M"] // 8) * 8 if p["M"] <= 128 else min(c["block_m"], p["M"])
     dp = _ceil_to(p["d"], 128)
     pq_m, dsub = _pq(p["d"])
     return {
         "bb": bb, "bm": bm, "dp": dp, "window": c["window"],
-        "xbuf_shape": (bb * bm, dp),
         "tbl": NS(shape=(p["n"], dp), dtype=4),
         "aux": NS(shape=(pq_m * 256, dsub)),
-        "jnp": _DTYPES,
+        "jnp": _DTYPES, **_ROWDMA,
     }
 
 
 def _edge_env(p, c):
+    K = _layers(p["n"]) * p["m"]
     return {
-        "bf": c["block_f"], "K": _layers(p["n"]) * p["m"],
+        "bf": c["block_f"], "K": K, "edges": NS(R=-(-K // 128)),
         "m_out": p["m_out"], "window": c["window"], "jnp": _DTYPES,
     }
 
@@ -117,10 +123,11 @@ def _prune_env(p, c):
     dp = _ceil_to(p["d"], 128)
     pq_m, dsub = _pq(p["d"])
     return {
-        "bb": bb, "C": p["C"], "m": p["m"], "window": c["window"],
+        "bb": bb, "C": _ceil_to(p["C"], 8), "m": p["m"],
+        "window": c["window"],
         "tp": NS(shape=(p["n"], dp), dtype=4),
         "aux": NS(shape=(pq_m * 256, dsub)),
-        "jnp": _DTYPES,
+        "jnp": _DTYPES, **_ROWDMA,
     }
 
 

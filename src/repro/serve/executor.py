@@ -79,10 +79,12 @@ class SearchExecutor:
                 )
         # the hot tables, uploaded once per leaf (possibly codec structs —
         # decode happens inside the jitted search / kernels, at the edge;
+        # neighbors go up in the EdgeRows form the hop kernel DMAs from;
         # NamedTuple codecs are pytrees, so their structure sits in the
         # trace signature and the zero-post-warmup-compile guarantee holds)
         self._vec = storage_mod.as_device(index.vectors)
-        self._nbrs = storage_mod.as_device(index.neighbors)
+        self._nbrs = storage_mod.as_device(
+            storage_mod.pack_edges(index.neighbors))
         self._rerank = storage_mod.as_device(getattr(index, "rerank", None))
         if faults:
             from repro.serve import faults as faults_mod

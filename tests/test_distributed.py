@@ -169,10 +169,13 @@ rng = np.random.default_rng(0)
 L = rng.integers(0, n // 2, B).astype(np.int32)
 R = (L + rng.integers(64, n // 2, B)).clip(max=n - 1).astype(np.int32)
 
+# each shard goes straight to its data-axis devices, neighbors in EdgeRows
+vec, nbr, bnd = sharded.place(mesh)
+assert vec.sharding.spec == jax.sharding.PartitionSpec("data")
+assert {s.data.shape[0] for s in nbr.rows.addressable_shards} == {1}
 ids, dists = dist.rfann_serve_step(
-    jnp.asarray(sharded.vectors), jnp.asarray(sharded.neighbors),
-    jnp.asarray(sharded.bounds), jnp.asarray(qv), jnp.asarray(L),
-    jnp.asarray(R), mesh=mesh, logn=sharded.logn, m=sharded.m, ef=64, k=10,
+    vec, nbr, bnd, jnp.asarray(qv), jnp.asarray(L), jnp.asarray(R),
+    mesh=mesh, logn=sharded.logn, m=sharded.m, ef=64, k=10,
 )
 ids = np.asarray(ids)
 
@@ -205,12 +208,6 @@ def test_sharded_rfann_matches_ground_truth():
     res = _run(_DIST_SCRIPT)
     assert res["in_range"]
     assert res["recall"] >= 0.9, res
-
-
-def _jax_has_shard_map():
-    import jax
-
-    return hasattr(jax, "shard_map")
 
 
 _RAGGED_COMPACT_SCRIPT = r"""
@@ -266,14 +263,10 @@ print(json.dumps({
 """
 
 
-@pytest.mark.skipif(not _jax_has_shard_map(),
-                    reason="needs jax.shard_map (jax >= 0.5)")
 def test_sharded_serve_step_compact_ragged_bit_identical():
     """Satellite of the compact-storage PR: int16 neighbors + bf16 vectors
     through the REAL shard_map serve step over ragged shards, ids
-    bit-identical to the f32 path fed the same pre-decoded data. The
-    mesh-free equivalent (``test_compact_serve_step_ids_bit_identical``)
-    covers jax builds without shard_map."""
+    bit-identical to the f32 path fed the same pre-decoded data."""
     res = _run(_RAGGED_COMPACT_SCRIPT)
     assert res["identical"], res
     assert res["in_range"], res

@@ -34,6 +34,41 @@ def test_neighbor_codec_roundtrip_preserves_sentinel():
     np.testing.assert_array_equal(dec, nbrs)
 
 
+@pytest.mark.parametrize("codec", ["int32", "int16", "split"])
+@pytest.mark.parametrize("layers,m", [(5, 8), (9, 16), (8, 16)])
+def test_edge_rows_roundtrip(codec, layers, m):
+    """EdgeRows (the kernels' row-DMA form) packs any neighbor codec into
+    whole 128-lane rows per node, -1 padded, and unpacks bit-identically;
+    (8, 16) is the K = 128 case with no padding."""
+    import jax
+
+    rng = np.random.default_rng(layers * m)
+    n = 1 << (layers - 1)
+    nbrs = rng.integers(0, n, (n, layers, m)).astype(np.int32)
+    nbrs[rng.random(nbrs.shape) < 0.3] = -1
+    if codec == "split":
+        # split offsets need edges inside their node's segment
+        lay = np.arange(layers)[None, :, None]
+        u = np.arange(n)[:, None, None]
+        lo = (u >> (layers - 1 - lay)) << (layers - 1 - lay)
+        width = 1 << (layers - 1 - lay)
+        nbrs = np.where(nbrs >= 0, lo + nbrs % width, -1).astype(np.int32)
+    enc = storage_mod.encode_neighbors(
+        nbrs, n, StorageConfig(neighbor_dtype=codec))
+    packed = storage_mod.pack_edges(enc)
+    R = -(-layers * m // 128)
+    assert packed.rows.shape == (n * R, 128)
+    assert packed.rows.dtype == np.int32
+    assert storage_mod.table_n(packed) == n
+    tail = packed.rows.reshape(n, R * 128)[:, layers * m:]
+    assert (tail == -1).all()
+    np.testing.assert_array_equal(
+        storage_mod.decode_neighbors(packed), nbrs)
+    dev = storage_mod.as_device(packed)
+    out = jax.jit(storage_mod.decode_neighbors)(dev)
+    np.testing.assert_array_equal(np.asarray(out), nbrs)
+
+
 def test_neighbor_dtype_auto_boundary():
     """int16 holds ids up to 32767, so n=32768 fits and n=32769 does not."""
     assert storage_mod.resolve_neighbor_dtype(32768, "auto") == np.int16
