@@ -320,16 +320,17 @@ def bench_autotune(iters=3, interpret=False):
     table_i8 = storage_mod.as_device(storage_mod.encode_vectors(
         np.asarray(table), storage_mod.StorageConfig.int8()))
 
+    edges = storage_mod.pack_edges(nbrs)    # the kernels' row-DMA form
     runs = {
         "hop": lambda **p: hop_k.hop_kernel_call(
-            q, table, nbrs, u, L, R, vis, exp_ok, logn=logn, m_out=m_out,
+            q, table, edges, u, L, R, vis, exp_ok, logn=logn, m_out=m_out,
             interpret=interpret, **p),
         "gather_dist": lambda **p: gather_k.gather_distance_kernel_call(
             q, table, gids, interpret=interpret, **p),
         "gather_dist_codec": lambda **p: gather_k.gather_distance_kernel_call(
             q, table_i8, gids, interpret=interpret, **p),
         "edge_select": lambda **p: edge_select_k.edge_select_kernel_call(
-            nbrs, u.reshape(B * W), L, R, logn=logn, m_out=m_out,
+            edges, u.reshape(B * W), L, R, logn=logn, m_out=m_out,
             interpret=interpret, **p),
         "prune": lambda **p: prune_k.prune_kernel_call(
             cand_ids, cand_dists, table, m=m, interpret=interpret, **p),

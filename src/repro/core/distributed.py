@@ -36,6 +36,7 @@ from repro.core import search as search_mod
 from repro.core import storage as storage_mod
 from repro.core.config import SearchConfig
 from repro.core.index import RangeGraphIndex
+from repro.kernels import ops
 
 __all__ = [
     "ShardedRangeIndex", "build_sharded", "shard_topk", "merge_topk",
@@ -73,21 +74,27 @@ class ShardedRangeIndex:
         return (self.vectors.nbytes + self.neighbors.nbytes
                 + self.bounds.nbytes)
 
-    def place(self, mesh: Mesh):
+    def place(self, mesh: Mesh, config: SearchConfig | None = None):
         """Upload the shards straight to their devices: each stacked table
         goes up with ``NamedSharding(mesh, P("data"))``, so shard ``s``
         lands on the ``data``-axis device that serves it (replicated over
         ``model``) instead of whole on one device first. Neighbors go up
-        in the ``storage.EdgeRows`` form the hop kernel reads. Returns the
+        in the ``storage.EdgeRows`` form where ``config``'s hop reads it
+        through a Pallas kernel (``ops.reads_edge_rows``). Returns the
         ``(vectors, neighbors, bounds)`` triple ``rfann_serve_step``
         takes."""
+        config = config or SearchConfig()
         put = functools.partial(
             jax.device_put, device=NamedSharding(mesh, P("data")))
-        packed = [storage_mod.pack_edges(t) for t in self.neighbors]
-        rows = put(np.stack([p.rows for p in packed]))
-        return (put(self.vectors),
-                storage_mod.EdgeRows(rows, packed[0].layers, packed[0].m),
-                put(self.bounds))
+        if ops.reads_edge_rows(config.hop_impl, config.edge_impl,
+                               config.dist_impl):
+            packed = [storage_mod.pack_edges(t) for t in self.neighbors]
+            nbrs = storage_mod.EdgeRows(
+                put(np.stack([p.rows for p in packed])),
+                packed[0].layers, packed[0].m)
+        else:
+            nbrs = put(self.neighbors)
+        return put(self.vectors), nbrs, put(self.bounds)
 
 
 def build_sharded(

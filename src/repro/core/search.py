@@ -70,6 +70,7 @@ __all__ = [
     "search_improvised",
     "search_fixed_layer",
     "search_filtered",
+    "neighbor_form",
 ]
 
 _INF = jnp.float32(jnp.inf)
@@ -351,6 +352,18 @@ def tile_frontier(x, expand_width):
     return jnp.repeat(x, expand_width, axis=0)
 
 
+def neighbor_form(nbrs, config: SearchConfig):
+    """The neighbor table in the form ``config``'s hop reads:
+    ``storage.EdgeRows`` where a Pallas kernel reads it
+    (``ops.reads_edge_rows``), the dense int32 ``[n, layers, m]``
+    otherwise. Either conversion is a full-table copy, so it belongs
+    outside any loop over hops."""
+    if ops.reads_edge_rows(config.hop_impl, config.edge_impl,
+                           config.dist_impl):
+        return storage_mod.pack_edges(nbrs)
+    return storage_mod.decode_neighbors(nbrs)
+
+
 # ---------------------------------------------------------------------------
 # Concrete searches
 # ---------------------------------------------------------------------------
@@ -369,9 +382,9 @@ def _search_improvised_jit(vectors, nbrs, queries, L, R, rerank_store=None,
     exact codecs) and re-cut to ``k`` — all inside this one jit, so the
     executor's compile accounting sees a single program.
     """
-    # the hop kernels read the EdgeRows form: pack here, once per call and
-    # outside the loop (a no-op for the executor's pre-packed upload)
-    nbrs = storage_mod.pack_edges(nbrs)
+    # once per call and outside the loop, the table takes the form the hop
+    # reads (a no-op for the executor's upload, already in that form)
+    nbrs = neighbor_form(nbrs, config)
     n = storage_mod.table_n(vectors)
     expand_width = effective_expand_width(config.expand_width, config.ef)
     entries = range_entry_ids(L, jnp.minimum(R, n - 1), n)
@@ -424,8 +437,9 @@ def search_improvised(
 
     L, R: int32[B] per-query inclusive rank ranges. ``vectors``/``nbrs`` may
     arrive in any storage codec (bf16/f16/int8/PQ vectors, int16/split ids
-    or ``storage.EdgeRows``): the neighbor table packs into ``EdgeRows``
-    once here, outside the hop loop; vectors stay
+    or ``storage.EdgeRows``): the neighbor table takes the form the hop
+    reads (:func:`neighbor_form`) once here, outside the hop loop; vectors
+    stay
     encoded end-to-end (the distance kernels decode in-register, DESIGN.md
     §9). ``rerank_store`` + ``config.rerank`` enable the in-jit exact
     refinement pass over the sidecar table.

@@ -42,25 +42,28 @@ __all__ = [
 ]
 
 # search spaces: small grids of tiles the TPU v5e compiler accepts at
-# n = 2^20, d = 128 (tests/test_tpu_compile.py compiles the defaults). Row
-# tiles are multiples of 8, the sublane tile: a block of 2 or 4 rows breaks
-# Mosaic's (8, 128) block rule, and block_m is a multiple of the 128 lanes.
+# n = 2^20, d = 128 for every table codec the kind serves (replint R4
+# prices them; tests/test_tpu_compile.py compiles the defaults). Row tiles
+# are multiples of 8, the sublane tile: a block of 2 or 4 rows breaks
+# Mosaic's (8, 128) block rule, and block_m is a multiple of the 128
+# lanes. The hop stays at 8 query rows: at 16 its two bitset blocks alone
+# (16 x 128 KiB, in and out, double-buffered) take 8 MiB of VMEM, and
+# with a wide beam (W = 32) the program overflows the 16 MiB.
 CANDIDATES = {
     "hop": [
-        {"block_b": bb, "window": w}
-        for bb in (8, 16) for w in (4, 8, 16)
+        {"block_b": 8, "window": w} for w in (4, 8, 16)
     ],
     "gather_dist": [
         {"block_b": bb, "block_m": bm, "window": w}
         for bb in (8, 16) for bm in (128, 256) for w in (8, 16)
     ],
-    # codec tables (int8/PQ) change the DMA row width (narrow int8/uint8
-    # rows, gathered as 8-row blocks) and add in-register decode work, so
-    # the optimal tile differs from the f32 table's — tuned as its own kind
-    # (DESIGN.md §9); the 8-row blocks cap the tile at 8 x 128 rows (a
-    # bf16 table overflows VMEM at 16 x 128)
+    # codec tables (int8/PQ) change the DMA row dtype (8-row blocks picked
+    # on arrival, kernels/rowdma.py) and add in-register decode work, so
+    # the optimal tile differs from the f32 table's — tuned as its own
+    # kind (DESIGN.md §9)
     "gather_dist_codec": [
-        {"block_b": 8, "block_m": 128, "window": w} for w in (8, 16, 32)
+        {"block_b": bb, "block_m": bm, "window": w}
+        for bb in (8, 16) for bm in (128, 256) for w in (8, 16, 32)
     ],
     "edge_select": [
         {"block_f": bf, "window": w}

@@ -27,12 +27,12 @@ formulation ``core/edge_select.py::select_edges_batch``) bit-for-bit in
 both variants; the math is integer-exact, so parity is equality, not
 tolerance.
 
-VMEM residency: lazy keeps only the flat ``[bf, K]`` buffers, so the
-default row tile is ``bf=8`` at every K. Eager's ``[bf, K, K]``
-intermediates dominate (at ``bf=8``, K=288 the masks pad to
-``8*288*384`` lanes, ~3.5 MB as i32; K=400 pads to 512 lanes, ~6.5 MB),
-so eager auto-drops ``block_f`` to 4 above K=384 — the cap lazy lifts.
-The gather scratch itself is tiny (``bf*K*4`` bytes). CPU/CI runs use
+VMEM residency: lazy keeps only the flat ``[bf, K]`` buffers. Eager's
+``[bf, K, K]`` intermediates dominate (at ``bf=8``, K=288 the masks pad
+to ``8*288*384`` lanes, ~3.5 MB as i32; K=400 pads to 512 lanes,
+~6.5 MB). The row tile is ``bf=8`` for both, the sublane tile (a 4-row
+block breaks Mosaic's (8, 128) rule). The gather scratch itself is tiny
+(``bf*R*128*4`` bytes). CPU/CI runs use
 ``interpret=True``.
 """
 from __future__ import annotations
@@ -122,42 +122,36 @@ def _edge_select_kernel(
                      "dedup", "interpret"),
 )
 def edge_select_kernel_call(
-    nbrs, us, L, R, *, logn, m_out, skip_layers=True, block_f=None,
+    edges, us, L, R, *, logn, m_out, skip_layers=True, block_f=8,
     window=8, dedup="lazy", interpret=False,
 ):
     """Fused per-hop edge improvisation (DESIGN.md §2/§3; oracle:
     ``ref.select_edges``).
 
-    nbrs [n, layers, m] in any neighbor codec (-1 sentinel) or the
-    ``storage.EdgeRows`` row-DMA form, us int32[F] (-1 masked), L/R scalars or
-    int32[F] -> int32[F, m_out] improvised edges, -1 padded. Ids are
-    bit-identical to the oracle across dtypes and backends.
+    edges the ``storage.EdgeRows`` row-DMA form of the neighbor table
+    (``storage.pack_edges``; -1 sentinel), us int32[F] (-1 masked), L/R
+    scalars or int32[F] -> int32[F, m_out] improvised edges, -1 padded.
+    Ids are bit-identical to the oracle across codecs and backends.
 
-    Pads F to the ``block_f`` row-tile multiple internally; the table is
-    passed in ``EdgeRows`` form (``storage.pack_edges``, a full-table copy
-    unless it already is) so each frontier node is one contiguous DMA.
-    ``dedup`` picks "lazy" (default, O(m_out*K)) or
-    "eager" (the [K, K] equality matrix, kept for A/B) — bit-identical ids.
+    Pads F to the ``block_f`` row-tile multiple internally; each frontier
+    node is one contiguous ``R``-row DMA. ``dedup`` picks "lazy" (default,
+    O(m_out*K)) or "eager" (the [K, K] equality matrix, kept for A/B) —
+    bit-identical ids.
     """
     if dedup not in ("lazy", "eager"):
         raise ValueError(
             f"edge_select: unknown dedup {dedup!r} "
             "(expected 'lazy' or 'eager')"
         )
-    edges = _storage.pack_edges(nbrs)
+    if not isinstance(edges, _storage.EdgeRows):
+        raise TypeError(f"edge_select_kernel_call reads storage.EdgeRows, "
+                        f"got {type(edges).__name__} (storage.pack_edges)")
     K, m = edges.layers * edges.m, edges.m
     F = us.shape[0]
     us = us.astype(jnp.int32)
     L = jnp.broadcast_to(jnp.asarray(L, jnp.int32), us.shape)
     R = jnp.broadcast_to(jnp.asarray(R, jnp.int32), us.shape)
-    # lazy dedup has no [bf, K, K] intermediate, so the row tile no longer
-    # shrinks above K=384
-    if block_f is not None:
-        bf = block_f
-    elif dedup == "lazy":
-        bf = 8
-    else:
-        bf = 8 if K <= 384 else 4
+    bf = block_f
 
     meta = jnp.stack(
         [us, L, R, jnp.zeros_like(us)], axis=1

@@ -25,10 +25,11 @@ struct, ``ids int32[B, M]`` -> ``f32[B, M]``. Math matches
 bit-for-bit in f32 under identical fusion: ``||x||^2 - 2 x.q + ||q||^2``
 for l2, ``-x.q`` for ip; invalid slots return ``+inf``.
 
-VMEM residency per program is ``bb*bm*row_bytes`` for the gather scratch
-(default tiles 8x128: 0.5 MB at f32 d=128; 16/8-bit tables DMA 8-row
-blocks, ``kernels/rowdma.py``: 2 MB bf16, 1 MB int8) plus the query tile
-and, for PQ, the ``[M*256, dsub]`` codebook (128 KB at d=128, M=32).
+VMEM residency per program is one f32 row per slot, ``bb*bm*d_pad*4``
+bytes for the gather scratch whatever the codec (default tiles 8x128:
+0.5 MB at d=128; 16/8-bit tables add a ``window``-block staging ring,
+``kernels/rowdma.py``) plus the query tile and, for PQ, the
+``[M*256, dsub]`` codebook (128 KB at d=128, M=32).
 The codec tiles are autotuned separately (``kind="gather_dist_codec"``,
 ``kernels/autotune.py``). CPU/CI runs use ``interpret=True``.
 """
@@ -51,30 +52,32 @@ def _gather_dist_kernel(
     q_ref,       # VMEM [bb, dp]
     ids_smem,    # SMEM [bb, bm] (DMA row indices)
     ids_vmem,    # VMEM [bb, bm] (vectorized mask)
-    *refs,       # table_ref (ANY), [aux_ref], o_ref, xbuf, sems
+    *refs,       # table_ref (ANY), [aux_ref], o_ref, xbuf, [stage], sems
     bb, bm, metric, window, codec, blocked, dp, pq_m, pq_dsub,
 ):
-    if codec is None:
-        table_ref, o_ref, xbuf, sems = refs
-    else:
-        table_ref, aux_ref, o_ref, xbuf, sems = refs
+    it = iter(refs)
+    table_ref = next(it)
+    aux_ref = next(it) if codec is not None else None
+    o_ref, xbuf = next(it), next(it)
+    stage = next(it) if blocked else None
+    sems = next(it)
     total = bb * bm
 
     def slot_id(t):
         return ids_smem[t // bm, t % bm]
 
-    _rows.gather(total, window, slot_id, lambda t: [
-        pltpu.make_async_copy(_rows.src(table_ref, slot_id(t), blocked),
-                              xbuf.at[t], sems.at[t % window])])
+    def scale(t):                                         # int8 row scale
+        return _rows.lane_pick(aux_ref[pl.ds(t // bm, 1), :], t % bm)
+
+    # codec decode, in-register (§9): int8 rows arrive scaled, PQ rows as
+    # their codes
+    _rows.gather_rows(table_ref, xbuf, stage, sems, total, window, slot_id,
+                      scale=scale if codec == "int8" else None)
 
     q = q_ref[...].astype(jnp.float32)       # [bb, dp]
     ids = ids_vmem[...]
-    # codec decode, in-register (§9): xbuf holds the *stored* rows
-    x = _rows.select(xbuf, _rows.to_column(ids) if blocked else None,
-                     blocked)                             # [bb*bm, w]
-    if codec == "int8":
-        x = x * _rows.to_column(aux_ref[...])             # per-row scales
-    elif codec == "pq":
+    x = xbuf[...]                                         # [bb*bm, w] f32
+    if codec == "pq":
         codes = x[:, :pq_m].astype(jnp.int32)             # [bb*bm, M]
         sub = jax.lax.broadcasted_iota(jnp.int32, (total, pq_m), 1)
         idx = codes + sub * _storage.PQ_CENTROIDS
@@ -150,22 +153,33 @@ def gather_distance_kernel_call(
     else:
         tbl, blocked = _rows.row_table(table)
 
+    # the SMEM copy of the ids is column-tile major, [Mp/bm, Bp, bm], so
+    # each (i, j) block spans its array's whole last dim
+    nj = idp.shape[1] // bm
+    ids_s = idp.reshape(idp.shape[0], nj, bm).transpose(1, 0, 2)
     in_specs = [
         pl.BlockSpec((bb, dp), lambda i, j: (i, 0)),
-        pl.BlockSpec((bb, bm), lambda i, j: (i, j),
+        pl.BlockSpec((None, bb, bm), lambda i, j: (j, i, 0),
                      memory_space=pltpu.SMEM),
         pl.BlockSpec((bb, bm), lambda i, j: (i, j)),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
-    args = [qp, idp, idp, tbl]
+    args = [qp, ids_s, idp, tbl]
     if codec is not None:
         in_specs.append(aux_spec)
         args.append(aux)
 
+    win = min(window, bb * bm)
+    scratch_shapes = [pltpu.VMEM((bb * bm, tbl.shape[-1]), jnp.float32)]
+    if blocked:
+        scratch_shapes.append(
+            pltpu.VMEM((win, _rows.ROWS, tbl.shape[-1]), tbl.dtype))
+    scratch_shapes.append(pltpu.SemaphoreType.DMA((win,)))
+
     out = pl.pallas_call(
         functools.partial(
             _gather_dist_kernel, bb=bb, bm=bm, metric=metric,
-            window=min(window, bb * bm), codec=codec, blocked=blocked,
+            window=win, codec=codec, blocked=blocked,
             dp=dp, pq_m=pq_m, pq_dsub=pq_dsub,
         ),
         grid=grid,
@@ -173,11 +187,7 @@ def gather_distance_kernel_call(
         out_specs=pl.BlockSpec((bb, bm), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qp.shape[0], idp.shape[1]),
                                        jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((bb * bm, _rows.ROWS, tbl.shape[-1]) if blocked
-                       else (bb * bm, tbl.shape[1]), tbl.dtype),
-            pltpu.SemaphoreType.DMA((min(window, bb * bm),)),
-        ],
+        scratch_shapes=scratch_shapes,
         interpret=interpret,
     )(*args)
     return out[:B, :M]

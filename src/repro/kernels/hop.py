@@ -28,10 +28,10 @@ hop so the frontier never leaves VMEM:
      first-occurrence rule of ``core/bitset.py``. (Mosaic lowers neither a
      general gather over the row nor an unsigned reduction.);
   4. **vector gather + distance** — the newly-visited ids DMA their vector
-     rows (8-row blocks for 16/8-bit tables, ``kernels/rowdma.py``)
-     straight from the un-blocked table into a VMEM scratch and one MXU
-     matmul emits masked f32 distances, exactly the ``gather_distance.py``
-     structure.
+     rows straight from the un-blocked table into one f32 VMEM row each
+     (16/8-bit tables land as 8-row blocks in a staging ring and keep only
+     the row, ``kernels/rowdma.py``) and one MXU matmul emits masked f32
+     distances, exactly the ``gather_distance.py`` structure.
 
 Semantics are ``kernels/ref.py::hop`` (select_edges -> bitset.test_and_set
 -> gather_dist): integer outputs (edges, newly-visited mask, bitset words)
@@ -39,9 +39,10 @@ must match bit-for-bit, distances to f32 tolerance.
 
 VMEM residency per program: the bitset tile ``bb*ceil(n/32)*4`` bytes,
 in and out, double-buffered, dominates at deployment n (default bb=8 at
-n = 2^20: 1 MiB each way), then the vector scratch ``bb*W*m_out*d_pad``
-rows (128 KiB f32 at W=4, m_out=16, d=128) and the edge scratch
-``bb*W*Rw*512`` bytes. CPU/CI runs use ``interpret=True``.
+n = 2^20: 1 MiB each way), then the vector scratch ``bb*W*m_out`` f32
+rows of ``d_pad`` lanes whatever the codec (256 KiB at bb=8, W=4,
+m_out=16, d=128) and the edge scratch ``bb*W*Rw*512`` bytes. CPU/CI runs
+use ``interpret=True``.
 """
 from __future__ import annotations
 
@@ -72,19 +73,18 @@ def _hop_kernel(
     nbrs_ref,    # ANY  [n*Rw, 128]  (packed edge rows, never blocked)
     table_ref,   # ANY  vector/code table in ``rowdma.row_table`` form
     *refs,       # [aux_ref], outputs, ebuf, esel, esel_s, gid_s, xbuf,
-                 # [sbuf], sems, [sems2]
+                 # [stage], [sstage], sems, [sems2]
     bb, W, K, Rw, m, m_out, logn, skip_layers, metric, window,
     codec, blocked, dp, pq_m, pq_dsub,
 ):
-    if codec is None:
-        (nbr_out, dist_out, nvalid_out, vis_out,
-         ebuf, esel, esel_s, gid_s, xbuf, sems) = refs
-    elif codec == "int8":
-        (aux_ref, nbr_out, dist_out, nvalid_out, vis_out,
-         ebuf, esel, esel_s, gid_s, xbuf, sbuf, sems, sems2) = refs
-    else:  # pq
-        (aux_ref, nbr_out, dist_out, nvalid_out, vis_out,
-         ebuf, esel, esel_s, gid_s, xbuf, sems) = refs
+    it = iter(refs)
+    aux_ref = next(it) if codec is not None else None
+    nbr_out, dist_out, nvalid_out, vis_out = (next(it) for _ in range(4))
+    ebuf, esel, esel_s, gid_s, xbuf = (next(it) for _ in range(5))
+    stage = next(it) if blocked else None
+    sstage = next(it) if codec == "int8" else None
+    sems = next(it)
+    sems2 = next(it) if codec == "int8" else None
     WM = W * m_out
     F = bb * W
     T = bb * WM
@@ -172,32 +172,28 @@ def _hop_kernel(
     def vec_id(t):
         return gid_s[t]
 
-    def vec_copies(t):
-        cps = [pltpu.make_async_copy(
-            _rows.src(table_ref, vec_id(t), blocked), xbuf.at[t],
-            sems.at[t % window])]
-        if codec == "int8":
-            # ids are discovered in-kernel, so the per-row scale rides a
-            # parallel DMA of its 128-scale row (aux_ref is ANY [n/128, 128])
-            cps.append(pltpu.make_async_copy(
-                aux_ref.at[vec_id(t) >> 7], sbuf.at[t],
-                sems2.at[t % window]))
-        return cps
+    def scale_copy(t):
+        # ids are discovered in-kernel, so the per-row scale rides a
+        # parallel DMA of its 128-scale row (aux_ref is ANY [n/128, 128])
+        return pltpu.make_async_copy(
+            aux_ref.at[vec_id(t) >> 7], sstage.at[t % window],
+            sems2.at[t % window])
 
-    _rows.gather(T, window, vec_id, vec_copies)
+    def scale(t):
+        return _rows.lane_pick(sstage[pl.ds(t % window, 1), :],
+                               vec_id(t) & 127)
+
+    # codec decode, in-register (DESIGN.md §9): int8 rows arrive scaled,
+    # PQ rows as their codes
+    int8 = codec == "int8"
+    _rows.gather_rows(table_ref, xbuf, stage, sems, T, window, vec_id,
+                      scale=scale if int8 else None,
+                      extra=(scale_copy,) if int8 else ())
 
     # -- distance: one MXU pass, keep the diagonal query<->row pairing ------
     q = q_ref[...].astype(jnp.float32)                    # [bb, dp]
-    gcol = (_rows.to_column(jnp.where(nvalid != 0, edges, -1))
-            if blocked or codec == "int8" else None)      # [T, 1]
-    # codec decode, in-register (DESIGN.md §9): xbuf holds the stored rows
-    x = _rows.select(xbuf, gcol, blocked)                 # [T, w]
-    if codec == "int8":
-        lane_s = jax.lax.broadcasted_iota(jnp.int32, (T, 128), 1)
-        scale = jnp.sum(jnp.where(lane_s == (gcol & 127), sbuf[...], 0.0),
-                        axis=1, keepdims=True)            # [T, 1]
-        x = x * scale
-    elif codec == "pq":
+    x = xbuf[...]                                         # [T, w] f32
+    if codec == "pq":
         codes = x[:, :pq_m].astype(jnp.int32)             # [T, M]
         sub_m = jax.lax.broadcasted_iota(jnp.int32, (T, pq_m), 1)
         idx = codes + sub_m * _storage.PQ_CENTROIDS
@@ -229,32 +225,31 @@ def _hop_kernel(
                      "window", "interpret"),
 )
 def hop_kernel_call(
-    q, table, nbrs, u, L, R, visited, exp_ok, *, logn, m_out,
+    q, table, edges, u, L, R, visited, exp_ok, *, logn, m_out,
     skip_layers=True, metric="l2", block_b=8, window=8, interpret=False,
 ):
     """One fused whole-hop launch (DESIGN.md §3). See ``kernels/ref.py::hop``
     for the semantic contract and shapes: q f32[B, d], table ([n, d] float /
-    Int8Vectors / PQVectors), nbrs ([n, layers, m] any codec, or the
-    ``storage.EdgeRows`` row-DMA form the serving path uploads), u
-    int32[B, W], L/R int32[B*W], visited uint32[B, words], exp_ok
-    bool[B, W]. Returns ``(nbr, ndist, nvalid, visited')``.
+    Int8Vectors / PQVectors), edges the ``storage.EdgeRows`` row-DMA form
+    of the neighbor table (``storage.pack_edges``), u int32[B, W], L/R
+    int32[B*W], visited uint32[B, words], exp_ok bool[B, W]. Returns
+    ``(nbr, ndist, nvalid, visited')``.
 
     Pads B to the ``block_b`` tile multiple (at least 8 rows, the sublane
     tile), the bitset rows to whole 128-word columns and the stored row
-    width to the 128 lane width (zero columns are exact for both metrics).
-    A ``[n, layers, m]`` edge table is repacked into whole 128-lane rows
-    per node (``storage.pack_edges``, a full-table copy: pass ``EdgeRows``
-    to skip it) and the vector table viewed in its row-DMA form
-    (``rowdma.row_table``), so every gather is one contiguous DMA. Codec
-    decode happens in-register after the DMA (DESIGN.md §9). Because the
-    gathered ids are *discovered inside* the kernel, the int8 per-row
+    width to the 128 lane width (zero columns are exact for both metrics),
+    and views the vector table in its row-DMA form (``rowdma.row_table``).
+    Codec decode happens in-register after the DMA (DESIGN.md §9). Because
+    the gathered ids are *discovered inside* the kernel, the int8 per-row
     scales cannot be pre-gathered like ``gather_distance.py`` does — they
     ride as an ``ANY [n/128, 128]`` f32 input with a parallel DMA of each
     id's 128-scale row (second semaphore array); the PQ codebook is a
     VMEM-resident input.
     """
+    if not isinstance(edges, _storage.EdgeRows):
+        raise TypeError(f"hop_kernel_call reads storage.EdgeRows, got "
+                        f"{type(edges).__name__} (storage.pack_edges)")
     B, d = q.shape
-    edges = _storage.pack_edges(nbrs)
     K, Rw = edges.layers * edges.m, edges.R
     W = u.shape[1]
     words = visited.shape[1]
@@ -317,11 +312,13 @@ def hop_kernel_call(
         pltpu.VMEM((bb * W, m_out), jnp.int32),
         pltpu.SMEM((bb * W, m_out), jnp.int32),
         pltpu.SMEM((bb * WM,), jnp.int32),
-        pltpu.VMEM((bb * WM, _rows.ROWS, tp.shape[-1]) if blocked
-                   else (bb * WM, tp.shape[1]), tp.dtype),
+        pltpu.VMEM((bb * WM, tp.shape[-1]), jnp.float32),
     ]
+    if blocked:
+        scratch_shapes.append(
+            pltpu.VMEM((win, _rows.ROWS, tp.shape[-1]), tp.dtype))
     if codec == "int8":
-        scratch_shapes.append(pltpu.VMEM((bb * WM, 128), jnp.float32))
+        scratch_shapes.append(pltpu.VMEM((win, 128), jnp.float32))
     scratch_shapes.append(pltpu.SemaphoreType.DMA((win,)))
     if codec == "int8":
         scratch_shapes.append(pltpu.SemaphoreType.DMA((win,)))

@@ -147,9 +147,11 @@ def select_edges(nbrs, us, L, R, *, logn, m_out, skip_layers=True,
         impl = default_impl("edge")
     _check_impl("select_edges", impl, {"pallas", "xla", "argsort"})
     if impl == "pallas":
-        # the kernel reads the EdgeRows form (packing decodes any codec)
+        # the kernel reads the EdgeRows form; a dense table packs here, a
+        # full-table copy per call (loops pack once, see reads_edge_rows)
         return _edge_select.edge_select_kernel_call(
-            nbrs, us, L, R, logn=logn, m_out=m_out, skip_layers=skip_layers,
+            _storage.pack_edges(nbrs), us, L, R, logn=logn, m_out=m_out,
+            skip_layers=skip_layers,
             interpret=_interpret(),
             **{**_autotune.get_pick("edge_select"), **block_kw},
         )
@@ -228,37 +230,9 @@ def prune(cand_ids, cand_dists, table, *, m, alpha=1.0, fill=True,
     )
 
 
-def hop(q, table, nbrs, u, L, R, visited, exp_ok, *, logn, m_out,
-        skip_layers=True, metric="l2", impl="auto", edge_impl="auto",
-        dist_impl="auto", **block_kw):
-    """One whole beam-search hop: edge improvisation + visited test-and-set
-    + gather-distance, the full ``beam_search`` iteration body.
-
-    "pallas" runs the fused megakernel (``kernels/hop.py``) — one launch,
-    frontier resident in VMEM; "xla" is the jnp composition
-    (``ref.hop``); "composed" chains the three *dispatched* ops
-    (``select_edges`` -> ``bitset.test_and_set`` -> ``gather_dist``), so
-    the per-op ``edge_impl`` / ``dist_impl`` knobs apply — it is the
-    bit-identical oracle and the pre-fusion production path. "auto" picks
-    pallas on TPU and "composed" off-TPU (keeping per-op knobs live);
-    ``REPRO_HOP_IMPL`` overrides that choice. The global ``REPRO_IMPL``
-    resolves "auto" to "composed" (its job is forcing the *per-op*
-    kernels, which run inside the composition; "legacy" maps the same
-    way) — only ``REPRO_HOP_IMPL`` or TPU auto engages the megakernel.
-    An explicit non-"auto" ``edge_impl``/``dist_impl`` pin always wins:
-    it routes any resolved impl through "composed", since the fused
-    kernel has no per-op backends. Integer outputs
-    (nbr, nvalid, visited) are bit-identical across backends; distances
-    agree to f32 tolerance.
-
-    Shapes: q f32[B, d], table ([n, d] float or a codec struct —
-    ``storage.Int8Vectors`` / ``storage.PQVectors``, decoded in-register by
-    the megakernel per DESIGN.md §9), nbrs [n, layers, m] in any neighbor
-    codec or ``storage.EdgeRows`` (the Pallas kernels read that form; the
-    jnp paths decode), u int32[B, W], L/R int32[B*W], visited
-    uint32[B, words], exp_ok bool[B, W] -> (nbr i32[B, W*m_out], ndist
-    f32[B, W*m_out], nvalid bool[B, W*m_out], visited' uint32[B, words]).
-    """
+def _resolve_hop(impl, edge_impl, dist_impl):
+    """``hop``'s dispatch rule (see its docstring): the resolved
+    ``(impl, edge_impl, dist_impl)``, impl one of pallas/xla/composed."""
     if impl == "auto":
         forced = _knobs.get_str("REPRO_HOP_IMPL")
         glob = _knobs.get_str("REPRO_IMPL")
@@ -297,6 +271,55 @@ def hop(q, table, nbrs, u, L, R, visited, exp_ok, *, logn, m_out,
         # bit-exactness) routes through the composed path even when
         # REPRO_HOP_IMPL forces "pallas"
         impl = "composed"
+    return impl, edge_impl, dist_impl
+
+
+def reads_edge_rows(hop_impl="auto", edge_impl="auto", dist_impl="auto"):
+    """Whether ``hop`` with these impls reads the neighbor table through a
+    Pallas kernel, i.e. in the ``storage.EdgeRows`` form. A caller that
+    runs many hops over one table packs it once when this holds (the
+    executor's upload, the jitted search) and keeps the dense form
+    otherwise, so no per-hop or per-call repack happens on either path."""
+    impl, edge_impl, _ = _resolve_hop(hop_impl, edge_impl, dist_impl)
+    if impl == "composed":
+        if edge_impl == "auto":
+            edge_impl = default_impl("edge")
+        return edge_impl == "pallas"
+    return impl == "pallas"
+
+
+def hop(q, table, nbrs, u, L, R, visited, exp_ok, *, logn, m_out,
+        skip_layers=True, metric="l2", impl="auto", edge_impl="auto",
+        dist_impl="auto", **block_kw):
+    """One whole beam-search hop: edge improvisation + visited test-and-set
+    + gather-distance, the full ``beam_search`` iteration body.
+
+    "pallas" runs the fused megakernel (``kernels/hop.py``) — one launch,
+    frontier resident in VMEM; "xla" is the jnp composition
+    (``ref.hop``); "composed" chains the three *dispatched* ops
+    (``select_edges`` -> ``bitset.test_and_set`` -> ``gather_dist``), so
+    the per-op ``edge_impl`` / ``dist_impl`` knobs apply — it is the
+    bit-identical oracle and the pre-fusion production path. "auto" picks
+    pallas on TPU and "composed" off-TPU (keeping per-op knobs live);
+    ``REPRO_HOP_IMPL`` overrides that choice. The global ``REPRO_IMPL``
+    resolves "auto" to "composed" (its job is forcing the *per-op*
+    kernels, which run inside the composition; "legacy" maps the same
+    way) — only ``REPRO_HOP_IMPL`` or TPU auto engages the megakernel.
+    An explicit non-"auto" ``edge_impl``/``dist_impl`` pin always wins:
+    it routes any resolved impl through "composed", since the fused
+    kernel has no per-op backends. Integer outputs
+    (nbr, nvalid, visited) are bit-identical across backends; distances
+    agree to f32 tolerance.
+
+    Shapes: q f32[B, d], table ([n, d] float or a codec struct —
+    ``storage.Int8Vectors`` / ``storage.PQVectors``, decoded in-register by
+    the megakernel per DESIGN.md §9), nbrs [n, layers, m] in any neighbor
+    codec or ``storage.EdgeRows`` (the Pallas kernels read that form; the
+    jnp paths decode), u int32[B, W], L/R int32[B*W], visited
+    uint32[B, words], exp_ok bool[B, W] -> (nbr i32[B, W*m_out], ndist
+    f32[B, W*m_out], nvalid bool[B, W*m_out], visited' uint32[B, words]).
+    """
+    impl, edge_impl, dist_impl = _resolve_hop(impl, edge_impl, dist_impl)
     if impl == "composed":
         B, W = u.shape
         nbr = select_edges(
@@ -317,9 +340,12 @@ def hop(q, table, nbrs, u, L, R, visited, exp_ok, *, logn, m_out,
             exp_ok, logn=logn, m_out=m_out, skip_layers=skip_layers,
             metric=metric,
         )
+    # the megakernel reads the EdgeRows form; a dense table packs here, a
+    # full-table copy per call (loops pack once, see reads_edge_rows)
     return _hop.hop_kernel_call(
-        q, table, nbrs, u, L, R, visited, exp_ok, logn=logn, m_out=m_out,
-        skip_layers=skip_layers, metric=metric, interpret=_interpret(),
+        q, table, _storage.pack_edges(nbrs), u, L, R, visited, exp_ok,
+        logn=logn, m_out=m_out, skip_layers=skip_layers, metric=metric,
+        interpret=_interpret(),
         **{**_autotune.get_pick("hop"), **block_kw},
     )
 

@@ -24,10 +24,12 @@ expansion, so parity holds under identical fusion.
 
 Duplicate candidate ids are wiped lazily inside the sweeps (no
 ``[bb, C, C]`` matrix: Mosaic cannot lower its rank-3 bool broadcast).
-VMEM residency per program: the gather scratch ``bb*C*d_pad*4`` bytes
-(default ``bb=8``, C=128, d=128: 0.5 MB) plus the ``[bb*C, C]`` row repeat
-that turns each sweep's one-hot selection into a column (0.5 MB as f32 at
-C=128); lower ``block_b`` for very large ``C*d``.
+VMEM residency per program: the gather scratch ``bb*C*d_pad*4`` bytes,
+one f32 row per slot whatever the codec (default ``bb=8``, C=128, d=128:
+0.5 MB; 16/8-bit tables add a ``window``-block staging ring) plus the
+``[bb*C, C]`` row repeat that turns each sweep's one-hot selection into a
+column (0.5 MB as f32 at C=128); lower ``block_b`` for very large
+``C*d``.
 CPU/CI runs use ``interpret=True``.
 """
 from __future__ import annotations
@@ -49,31 +51,33 @@ def _prune_kernel(
     ids_smem,    # SMEM [bb, C] (DMA row indices)
     ids_vmem,    # VMEM [bb, C] (vectorized ids)
     du_ref,      # VMEM [bb, C] squared distances to u
-    *refs,       # table_ref (ANY), [aux_ref], o_ref, xbuf, sems
+    *refs,       # table_ref (ANY), [aux_ref], o_ref, xbuf, [stage], sems
     bb, C, m, alpha, fill, window, codec, blocked, pq_m, pq_dsub,
 ):
-    if codec is None:
-        table_ref, o_ref, xbuf, sems = refs
-    else:
-        table_ref, aux_ref, o_ref, xbuf, sems = refs
+    it = iter(refs)
+    table_ref = next(it)
+    aux_ref = next(it) if codec is not None else None
+    o_ref, xbuf = next(it), next(it)
+    stage = next(it) if blocked else None
+    sems = next(it)
     total = bb * C
     big = jnp.int32(2**30)
 
     def slot_id(t):
         return ids_smem[t // C, t % C]
 
-    _rows.gather(total, window, slot_id, lambda t: [
-        pltpu.make_async_copy(_rows.src(table_ref, slot_id(t), blocked),
-                              xbuf.at[t], sems.at[t % window])])
+    def scale(t):                                         # int8 row scale
+        return _rows.lane_pick(aux_ref[pl.ds(t // C, 1), :], t % C)
+
+    # codec decode, in-register (DESIGN.md §9): int8 rows arrive scaled,
+    # PQ rows as their codes
+    _rows.gather_rows(table_ref, xbuf, stage, sems, total, window, slot_id,
+                      scale=scale if codec == "int8" else None)
 
     ids = ids_vmem[...]                                   # [bb, C]
     du = du_ref[...]                                      # [bb, C]
-    # codec decode, in-register (DESIGN.md §9): xbuf holds the stored rows
-    x = _rows.select(xbuf, _rows.to_column(ids) if blocked else None,
-                     blocked)                             # [bb*C, w]
-    if codec == "int8":
-        x = x * _rows.to_column(aux_ref[...])             # per-row scales
-    elif codec == "pq":
+    x = xbuf[...]                                         # [bb*C, w] f32
+    if codec == "pq":
         codes = x[:, :pq_m].astype(jnp.int32)
         sub = jax.lax.broadcasted_iota(jnp.int32, (total, pq_m), 1)
         idx = codes + sub * _storage.PQ_CENTROIDS
@@ -200,21 +204,24 @@ def prune_kernel_call(
         in_specs.append(aux_spec)
         args.append(aux)
 
+    win = min(window, bb * C)
+    scratch_shapes = [pltpu.VMEM((bb * C, tp.shape[-1]), jnp.float32)]
+    if blocked:
+        scratch_shapes.append(
+            pltpu.VMEM((win, _rows.ROWS, tp.shape[-1]), tp.dtype))
+    scratch_shapes.append(pltpu.SemaphoreType.DMA((win,)))
+
     out = pl.pallas_call(
         functools.partial(
             _prune_kernel, bb=bb, C=C, m=m, alpha=alpha, fill=fill,
-            window=min(window, bb * C), codec=codec, blocked=blocked,
+            window=win, codec=codec, blocked=blocked,
             pq_m=pq_m, pq_dsub=pq_dsub,
         ),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bb, m), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((idp.shape[0], m), jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM((bb * C, _rows.ROWS, tp.shape[-1]) if blocked
-                       else (bb * C, tp.shape[1]), tp.dtype),
-            pltpu.SemaphoreType.DMA((min(window, bb * C),)),
-        ],
+        scratch_shapes=scratch_shapes,
         interpret=interpret,
     )(*args)
     return out[:B]

@@ -5,27 +5,30 @@ stays in ``ANY``/HBM space, never blocked). On a TPU the table lives in a
 tiled HBM layout, and a DMA may only move whole tiles along the row axis:
 
   * a 32-bit table exactly one lane tile wide (``[n, 128]``) is stored row
-    after row, so one row is one contiguous DMA;
+    after row, so one row is one contiguous DMA straight into its slot;
   * every other table (bf16 ``(8,128)(2,1)``, int8 ``(8,128)(4,1)``, or a
     32-bit table wider than 128 lanes) packs 8 rows per tile, so the DMA
-    moves the 8-row block that holds the row and the kernel picks the row
-    out in VMEM (:func:`select`).
+    moves the 8-row block that holds the row into a staging ring of
+    ``window`` blocks, and on arrival the kernel picks the row out (and
+    scales it, for int8) into its f32 slot.
 
+Either way a gathered slot costs one f32 row of VMEM, whatever the codec.
 :func:`row_table` puts a table into the form its kernel DMAs from and says
-which of the two it is. Two more helpers cover what Mosaic cannot lower
-directly: :func:`to_column` turns a ``[R, C]`` tile into the ``[R*C, 1]``
-column that lines up with gathered rows (a plain reshape moves lanes to
-sublanes, which Mosaic refuses), and :func:`gather` is the software
-pipeline every kernel runs its row DMAs through.
+which of the two it is; :func:`gather_rows` runs the row gather through
+:func:`gather`, the software pipeline every kernel's DMAs go through.
+:func:`to_column` turns a ``[R, C]`` tile into the ``[R*C, 1]`` column
+that lines up with gathered rows (a plain reshape moves lanes to sublanes,
+which Mosaic refuses).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["ROWS", "pad_to", "row_table", "src", "gather", "select",
-           "to_column"]
+__all__ = ["ROWS", "pad_to", "row_table", "gather", "gather_rows",
+           "lane_pick", "to_column"]
 
 # rows per HBM tile along the row axis, for every dtype the tables use
 ROWS = 8
@@ -53,15 +56,12 @@ def row_table(table):
     return tbl.reshape(tbl.shape[0] // ROWS, ROWS, tbl.shape[1]), True
 
 
-def src(table_ref, i, blocked):
-    """The HBM slice one DMA moves for row ``i``."""
-    return table_ref.at[i >> 3] if blocked else table_ref.at[i]
-
-
-def gather(total, window, idx, copies):
+def gather(total, window, idx, copies, arrive=None):
     """Software-pipelined DMA loop over slots ``0..total-1``: keep up to
     ``window`` slots in flight; ``copies(t)`` gives slot ``t``'s DMA
-    descriptors (semaphore ``t % window``), skipped when ``idx(t) < 0``."""
+    descriptors (semaphore ``t % window``), skipped when ``idx(t) < 0``.
+    ``arrive(t)``, when given, runs right after slot ``t``'s copies land
+    and before slot ``t + window`` reuses its semaphore (and staging)."""
     def start(t):
         @pl.when(idx(t) >= 0)
         def _():
@@ -73,6 +73,8 @@ def gather(total, window, idx, copies):
         def _():
             for c in copies(t):
                 c.wait()
+            if arrive is not None:
+                arrive(t)
 
     def fill(t, carry):
         @pl.when(t >= window)
@@ -91,17 +93,42 @@ def gather(total, window, idx, copies):
     jax.lax.fori_loop(max(0, total - window), total, drain, 0)
 
 
-def select(xbuf, ids_col, blocked):
-    """Gathered rows as f32 ``[slots, w]``. For 8-row blocks, slot ``t``
-    keeps row ``ids_col[t] % 8`` of its block (``ids_col`` int32
-    ``[slots, 1]``); slots whose id was ``< 0`` hold garbage the caller
-    masks."""
-    x = xbuf[...].astype(jnp.float32)
-    if not blocked:
-        return x
-    sub = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    pick = sub == (ids_col & (ROWS - 1))[:, :, None]
-    return jnp.sum(jnp.where(pick, x, 0.0), axis=1)
+def gather_rows(table_ref, xbuf, stage, sems, total, window, idx,
+                scale=None, extra=()):
+    """Gather table row ``idx(t)`` into ``xbuf[t]`` (f32 ``[total, w]``)
+    for every slot with ``idx(t) >= 0``; other slots hold garbage the
+    caller masks. ``stage`` is ``None`` for a one-tile 32-bit table (the
+    row DMAs straight into its slot), else the ``[window, ROWS, w]``
+    staging ring the row's 8-row block lands in. ``scale(t)`` (a ``[1, 1]``
+    f32, read after the copies land) multiplies the picked row; ``extra``
+    adds per-slot DMA descriptors ``e(t)`` that ride on the same
+    pipeline."""
+    def copies(t):
+        i = idx(t)
+        if stage is None:
+            src, dst = table_ref.at[i], xbuf.at[t]
+        else:
+            src, dst = table_ref.at[i >> 3], stage.at[t % window]
+        return [pltpu.make_async_copy(src, dst, sems.at[t % window]),
+                *(e(t) for e in extra)]
+
+    def arrive(t):
+        blk = stage[t % window].astype(jnp.float32)       # [ROWS, w]
+        sub = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
+        row = jnp.sum(jnp.where(sub == (idx(t) & (ROWS - 1)), blk, 0.0),
+                      axis=0, keepdims=True)              # [1, w]
+        if scale is not None:
+            row = row * scale(t)
+        xbuf[pl.ds(t, 1), :] = row
+
+    gather(total, window, idx, copies, None if stage is None else arrive)
+
+
+def lane_pick(row, j):
+    """Lane ``j`` of a ``[1, w]`` row, as a ``[1, 1]`` array (Mosaic has
+    no dynamic lane index)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == j, row, 0.0), axis=1, keepdims=True)
 
 
 def to_column(a):

@@ -13,10 +13,17 @@ symbol environment computed from the probe shape and the candidate
 params using the kernels' own tiling formulas. ``SMEM``/``ANY`` specs
 and DMA semaphores don't occupy VMEM blocks and are skipped; block
 elements are costed at 4 bytes (f32/int32 worst case) and in/out blocks
-are doubled for pipelining double-buffering. An expression the
-evaluator cannot reduce is itself a finding, so a new shape idiom in a
-kernel forces this rule (and its env) to be taught about it rather than
-silently passing.
+are doubled for pipelining double-buffering. Every array is priced at
+its VMEM tile footprint: the minor dim rounds up to 128 lanes and the
+second-minor to the dtype's sublane tile (8 rows of 32-bit, 16 of
+16-bit, 32 of 8-bit). A kernel that gathers table rows is checked once
+per table codec its autotune kind serves (``TABLES``), each with the
+shape, itemsize and 8-row blocking ``kernels/rowdma.py::row_table``
+gives that codec. Compiler temporaries are not priced;
+``tests/test_tpu_compile.py`` compiles the defaults for a real v5e. An
+expression the evaluator cannot reduce is itself a finding, so a new
+shape idiom in a kernel forces this rule (and its env) to be taught
+about it rather than silently passing.
 
 Completeness is checked both ways: every ``CANDIDATES`` kind must map
 to a kernel, and every module under ``kernels/`` that calls
@@ -55,6 +62,23 @@ PROBES = {
         B=64, n=100_000, d=128, m=16, W=32, m_out=16, C=256, M=512,
         Sq=2048, Skv=2048, Dh=128,
     ),
+    # deployment scale, n = 2^20, where the visited bitset rows (128 KiB
+    # per query) dominate the hop's blocks; beam width as generous as the
+    # production probe's, C = m + ef_construction as a build level prunes
+    "deployment": dict(
+        B=64, n=1 << 20, d=128, m=16, W=32, m_out=16, C=80, M=512,
+        Sq=2048, Skv=2048, Dh=128,
+    ),
+}
+
+# Table codecs each row-gathering autotune kind serves: the f32/bf16
+# float tables share "gather_dist", the int8/PQ codecs "gather_dist_codec"
+# (``kernels/ops.py::gather_dist``); hop and prune take every codec.
+TABLES = {
+    "hop": ("f32", "bf16", "int8", "pq"),
+    "gather_dist": ("f32", "bf16"),
+    "gather_dist_codec": ("int8", "pq"),
+    "prune": ("f32", "bf16", "int8", "pq"),
 }
 
 
@@ -73,44 +97,51 @@ def _pq(d: int) -> tuple[int, int]:
     return max(1, d // dsub), dsub
 
 
-# Gathered-row scratch is costed for a one-lane-tile 32-bit table, one row
-# per slot (``blocked=False``); a 16/8-bit table DMAs 8-row blocks
-# (``kernels/rowdma.py``), 2-4x these bytes.
-_ROWDMA = {"blocked": False, "_rows": NS(ROWS=8)}
+def _row_table(p, codec):
+    """The table as ``rowdma.row_table`` hands it to a kernel: lanes
+    padded to 128; a table that is not one 32-bit lane tile wide viewed as
+    ``[n/8, 8, w]`` 8-row blocks."""
+    itemsize = {"f32": 4, "bf16": 2, "int8": 1, "pq": 1}[codec]
+    w = 128 if codec == "pq" else _ceil_to(p["d"], 128)
+    if itemsize == 4 and w == 128:
+        return NS(shape=(p["n"], w), dtype=itemsize)
+    return NS(shape=(-(-p["n"] // 8), 8, w), dtype=itemsize)
 
 
-def _hop_env(p, c):
+def _table_env(p, codec):
+    pq_m, dsub = _pq(p["d"])
+    tbl = _row_table(p, codec)
+    return {
+        "tp": tbl, "tbl": tbl, "aux": NS(shape=(pq_m * 256, dsub)),
+        "_rows": NS(ROWS=8), "jnp": _DTYPES,
+    }
+
+
+def _hop_env(p, c, codec):
     layers = _layers(p["n"])
     K = layers * p["m"]
     bb = min(c["block_b"], max(8, p["B"]))
-    WM = p["W"] * p["m_out"]
-    dp = _ceil_to(p["d"], 128)
-    pq_m, dsub = _pq(p["d"])
     return {
-        "bb": bb, "W": p["W"], "K": K, "WM": WM, "dp": dp,
+        "bb": bb, "W": p["W"], "K": K, "WM": p["W"] * p["m_out"],
+        "dp": _ceil_to(p["d"], 128),
         "wp": _ceil_to(-(-p["n"] // 32), 128), "Rw": -(-K // 128),
         "win": max(1, min(c["window"], bb * p["W"])),
         "m_out": p["m_out"], "window": c["window"],
-        "tp": NS(shape=(p["n"], dp), dtype=4),
-        "aux": NS(shape=(pq_m * 256, dsub)),
-        "jnp": _DTYPES, **_ROWDMA,
+        **_table_env(p, codec),
     }
 
 
-def _gather_env(p, c):
+def _gather_env(p, c, codec):
     bb = min(c["block_b"], max(8, p["B"]))
     bm = -(-p["M"] // 8) * 8 if p["M"] <= 128 else min(c["block_m"], p["M"])
-    dp = _ceil_to(p["d"], 128)
-    pq_m, dsub = _pq(p["d"])
     return {
-        "bb": bb, "bm": bm, "dp": dp, "window": c["window"],
-        "tbl": NS(shape=(p["n"], dp), dtype=4),
-        "aux": NS(shape=(pq_m * 256, dsub)),
-        "jnp": _DTYPES, **_ROWDMA,
+        "bb": bb, "bm": bm, "dp": _ceil_to(p["d"], 128),
+        "window": c["window"], "win": min(c["window"], bb * bm),
+        **_table_env(p, codec),
     }
 
 
-def _edge_env(p, c):
+def _edge_env(p, c, codec=None):
     K = _layers(p["n"]) * p["m"]
     return {
         "bf": c["block_f"], "K": K, "edges": NS(R=-(-K // 128)),
@@ -118,20 +149,16 @@ def _edge_env(p, c):
     }
 
 
-def _prune_env(p, c):
+def _prune_env(p, c, codec):
     bb = min(c["block_b"], max(8, p["B"]))
-    dp = _ceil_to(p["d"], 128)
-    pq_m, dsub = _pq(p["d"])
+    C = _ceil_to(p["C"], 8)
     return {
-        "bb": bb, "C": _ceil_to(p["C"], 8), "m": p["m"],
-        "window": c["window"],
-        "tp": NS(shape=(p["n"], dp), dtype=4),
-        "aux": NS(shape=(pq_m * 256, dsub)),
-        "jnp": _DTYPES, **_ROWDMA,
+        "bb": bb, "C": C, "m": p["m"], "window": c["window"],
+        "win": min(c["window"], bb * C), **_table_env(p, codec),
     }
 
 
-def _dist_env(p, c):
+def _dist_env(p, c, codec=None):
     return {
         "bq": min(c["block_q"], max(8, p["B"])),
         "bn": min(c["block_n"], max(8, p["n"])),
@@ -140,7 +167,7 @@ def _dist_env(p, c):
     }
 
 
-def _flash_env(p, c):
+def _flash_env(p, c, codec=None):
     return {
         "bq": min(c["block_q"], max(8, p["Sq"])),
         "bk": min(c["block_k"], max(8, p["Skv"])),
@@ -204,22 +231,28 @@ def _nbytes(shape_expr, dtype_expr, env) -> int:
     shape = astutil.eval_shape(shape_expr, env)
     if isinstance(shape, (int, float)):
         shape = (shape,)
-    total = 1
+    shape = list(shape)
     for e in shape:
         if not isinstance(e, int) or e <= 0:
             raise astutil.EvalError(
                 f"non-positive/non-int dim {e!r} in "
                 f"{ast.unparse(shape_expr)}"
             )
-        total *= e
     itemsize = 4
     if dtype_expr is not None:
         itemsize = astutil.eval_shape(dtype_expr, env)
-        if not isinstance(itemsize, int):
+        if not isinstance(itemsize, int) or itemsize not in (1, 2, 4):
             raise astutil.EvalError(
                 f"dtype {ast.unparse(dtype_expr)} -> {itemsize!r}"
             )
-    return total * itemsize
+    # the VMEM tile: 128 lanes by 8 rows of 32 bits (16 of 16-bit, ...)
+    shape[-1] = _ceil_to(shape[-1], 128)
+    if len(shape) > 1:
+        shape[-2] = _ceil_to(shape[-2], 8 * 4 // itemsize)
+    total = itemsize
+    for e in shape:
+        total *= e
+    return total
 
 
 def check(ctx):
@@ -278,37 +311,40 @@ def check(ctx):
                     f"unknown-kind:{kind}",
                 )
                 continue
-            for probe_name, probe in PROBES.items():
-                for cand in grid:
-                    try:
-                        env = env_fn(probe, cand)
-                        total = sum(
-                            _nbytes(s, d, env) * (2 if k == "block" else 1)
-                            for k, s, d in decls
-                        )
-                    except astutil.EvalError as e:
-                        yield ctx.finding(
-                            RULE_ID, path, fn,
-                            f"{call_name}: cannot evaluate a VMEM shape "
-                            f"for {label}/{probe_name} {cand}: {e} — "
-                            f"teach r4_vmem_budget the new idiom",
-                            f"{call_name}:uneval:{e}",
-                        )
-                        break
-                    if total > BUDGET_BYTES:
-                        cd = ",".join(
-                            f"{k}={cand[k]}" for k in sorted(cand)
-                        )
-                        yield ctx.finding(
-                            RULE_ID, path, fn,
-                            f"{call_name}: candidate {{{cd}}} needs "
-                            f"{total / 2**20:.2f} MiB VMEM at the "
-                            f"{probe_name} shape — over the "
-                            f"{BUDGET_BYTES >> 20} MiB budget DESIGN.md "
-                            f"claims; shrink the tile or drop it from "
-                            f"CANDIDATES[{label!r}]",
-                            f"{call_name}:{label}:{probe_name}:{cd}",
-                        )
+            runs = [
+                (probe_name, probe, codec, cand)
+                for probe_name, probe in PROBES.items()
+                for codec in TABLES.get(kind, (None,))
+                for cand in grid
+            ]
+            for probe_name, probe, codec, cand in runs:
+                where = probe_name + (f"/{codec}" if codec else "")
+                try:
+                    env = env_fn(probe, cand, codec)
+                    total = sum(
+                        _nbytes(s, d, env) * (2 if k == "block" else 1)
+                        for k, s, d in decls
+                    )
+                except astutil.EvalError as e:
+                    yield ctx.finding(
+                        RULE_ID, path, fn,
+                        f"{call_name}: cannot evaluate a VMEM shape "
+                        f"for {label}/{where} {cand}: {e} — "
+                        f"teach r4_vmem_budget the new idiom",
+                        f"{call_name}:uneval:{e}",
+                    )
+                    break
+                if total > BUDGET_BYTES:
+                    cd = ",".join(f"{k}={cand[k]}" for k in sorted(cand))
+                    yield ctx.finding(
+                        RULE_ID, path, fn,
+                        f"{call_name}: candidate {{{cd}}} needs "
+                        f"{total / 2**20:.2f} MiB VMEM at the {where} "
+                        f"shape — over the {BUDGET_BYTES >> 20} MiB budget "
+                        f"DESIGN.md claims; shrink the tile or drop it "
+                        f"from CANDIDATES[{label!r}]",
+                        f"{call_name}:{label}:{where}:{cd}",
+                    )
 
     for kind in candidates:
         if kind not in covered_kinds:

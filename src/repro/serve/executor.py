@@ -36,6 +36,7 @@ from repro.core import knobs as knobs_mod
 from repro.core import search as search_mod
 from repro.core import storage as storage_mod
 from repro.core.config import SearchConfig
+from repro.kernels import ops
 
 __all__ = ["SearchExecutor"]
 
@@ -79,12 +80,16 @@ class SearchExecutor:
                 )
         # the hot tables, uploaded once per leaf (possibly codec structs —
         # decode happens inside the jitted search / kernels, at the edge;
-        # neighbors go up in the EdgeRows form the hop kernel DMAs from;
-        # NamedTuple codecs are pytrees, so their structure sits in the
-        # trace signature and the zero-post-warmup-compile guarantee holds)
+        # neighbors go up in the EdgeRows form where the hop kernels DMA
+        # from it, else in their stored codec; NamedTuple codecs are
+        # pytrees, so their structure sits in the trace signature and the
+        # zero-post-warmup-compile guarantee holds)
+        cfg = self.config
+        nbrs = index.neighbors
+        if ops.reads_edge_rows(cfg.hop_impl, cfg.edge_impl, cfg.dist_impl):
+            nbrs = storage_mod.pack_edges(nbrs)
         self._vec = storage_mod.as_device(index.vectors)
-        self._nbrs = storage_mod.as_device(
-            storage_mod.pack_edges(index.neighbors))
+        self._nbrs = storage_mod.as_device(nbrs)
         self._rerank = storage_mod.as_device(getattr(index, "rerank", None))
         if faults:
             from repro.serve import faults as faults_mod

@@ -151,7 +151,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType
 
-from repro.core import BuildConfig, RangeGraphIndex, recall
+from repro.core import BuildConfig, RangeGraphIndex, SearchConfig, recall
 from repro.core import distributed as dist
 from repro.data.pipeline import vector_dataset
 
@@ -169,8 +169,12 @@ rng = np.random.default_rng(0)
 L = rng.integers(0, n // 2, B).astype(np.int32)
 R = (L + rng.integers(64, n // 2, B)).clip(max=n - 1).astype(np.int32)
 
-# each shard goes straight to its data-axis devices, neighbors in EdgeRows
-vec, nbr, bnd = sharded.place(mesh)
+# each shard goes straight to its data-axis devices; a config whose hop
+# runs the Pallas kernels sends neighbors up in EdgeRows (the jnp hop that
+# serves here decodes them), the default one in their stored form
+dense = sharded.place(mesh)[1]
+assert {s.data.shape[0] for s in dense.addressable_shards} == {1}
+vec, nbr, bnd = sharded.place(mesh, SearchConfig(hop_impl="pallas"))
 assert vec.sharding.spec == jax.sharding.PartitionSpec("data")
 assert {s.data.shape[0] for s in nbr.rows.addressable_shards} == {1}
 ids, dists = dist.rfann_serve_step(

@@ -156,9 +156,11 @@ def test_hop_bitset_saturation_at_n_boundary(impl, n):
 def test_hop_kernel_block_sizes():
     """Tile/pipeline knobs change scheduling, never results."""
     p = _mk(B=5, seed=13)
-    base = hop_kernel_call(*p["args"], interpret=True, **p["kw"])
+    args = list(p["args"])
+    args[2] = storage_mod.pack_edges(args[2])   # the kernel's row-DMA form
+    base = hop_kernel_call(*args, interpret=True, **p["kw"])
     for bb, w in ((1, 2), (2, 4), (8, 16)):
-        got = hop_kernel_call(*p["args"], block_b=bb, window=w,
+        got = hop_kernel_call(*args, block_b=bb, window=w,
                               interpret=True, **p["kw"])
         _assert_hop_equal(got, base)
 
@@ -306,8 +308,9 @@ def test_edge_select_lazy_matches_eager(K_big):
     L = jnp.asarray(rng.integers(0, n // 2, size=(F,)).astype(np.int32))
     R = L + 200
     kw = dict(logn=logn, m_out=8, interpret=True)
-    lazy = edge_select_kernel_call(nbrs, us, L, R, dedup="lazy", **kw)
-    eager = edge_select_kernel_call(nbrs, us, L, R, dedup="eager", **kw)
+    edges = storage_mod.pack_edges(nbrs)
+    lazy = edge_select_kernel_call(edges, us, L, R, dedup="lazy", **kw)
+    eager = edge_select_kernel_call(edges, us, L, R, dedup="eager", **kw)
     ref = ops.select_edges(nbrs, us, L, R, logn=logn, m_out=8, impl="xla")
     np.testing.assert_array_equal(np.asarray(lazy), np.asarray(eager))
     np.testing.assert_array_equal(np.asarray(lazy), np.asarray(ref))
@@ -319,5 +322,6 @@ def test_edge_select_unknown_dedup_rejected():
     us = jnp.asarray([0, 1], jnp.int32)
     with pytest.raises(ValueError, match="unknown dedup"):
         edge_select_kernel_call(
-            nbrs, us, jnp.zeros(2, jnp.int32), jnp.full(2, 15, jnp.int32),
-            logn=4, m_out=4, dedup="nope", interpret=True)
+            storage_mod.pack_edges(nbrs), us, jnp.zeros(2, jnp.int32),
+            jnp.full(2, 15, jnp.int32), logn=4, m_out=4, dedup="nope",
+            interpret=True)

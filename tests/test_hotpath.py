@@ -30,7 +30,9 @@ from repro.kernels.gather_distance import gather_distance_kernel_call
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("metric", ["l2", "ip"])
-@pytest.mark.parametrize("B,n,d,M", [(3, 64, 16, 9), (8, 128, 48, 16)])
+# the last case tiles both grid axes (B and M past one tile each)
+@pytest.mark.parametrize("B,n,d,M", [(3, 64, 16, 9), (8, 128, 48, 16),
+                                     (9, 256, 32, 200)])
 def test_gather_distance_matches_oracle(metric, B, n, d, M):
     rng = np.random.default_rng(B * 100 + M)
     q = jnp.asarray(rng.standard_normal((B, d)), jnp.float32)

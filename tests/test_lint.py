@@ -297,6 +297,30 @@ def test_r4_fires_on_unmapped_kind_and_uncovered_kernel(tmp_path,
     assert any(s.startswith("missing-module:") for s in got)
 
 
+def test_r4_prices_each_codec_at_its_vmem_tile():
+    """A gathered 8-row block costs its dtype's whole sublane tile, so a
+    per-slot block of a bf16 or int8 table is priced 8x a one-row f32
+    slot — the blow-up that kept 16/8-bit tables from fitting VMEM."""
+    import ast
+
+    env = r4_vmem_budget._gather_env(
+        r4_vmem_budget.PROBES["deployment"],
+        {"block_b": 8, "block_m": 128, "window": 8}, "bf16")
+    assert env["tbl"].shape[1:] == (8, 128) and env["tbl"].dtype == 2
+
+    def cost(expr, dtype):
+        return r4_vmem_budget._nbytes(
+            ast.parse(expr, mode="eval").body,
+            ast.parse(dtype, mode="eval").body, env)
+
+    row = cost("(bb * bm, tbl.shape[-1])", "jnp.float32")
+    block = cost("(bb * bm, _rows.ROWS, tbl.shape[-1])", "tbl.dtype")
+    assert row == 8 * 128 * 128 * 4
+    assert block == 8 * row
+    for codec in ("f32", "bf16", "int8", "pq"):
+        assert codec in r4_vmem_budget.TABLES["hop"]
+
+
 def test_r4_clean_on_repo():
     got = [
         f for f in r4_vmem_budget.check(Context(root=REPO))

@@ -6,8 +6,10 @@ slices of the tiled HBM layout, vector shape casts Mosaic cannot lower,
 VMEM overflow. These tests compile each kernel ahead of time for a v5e
 chip that is described, not attached (nothing runs), at n = 2^20, d = 128,
 B = 64, W = 4, m = 16 with the default tiles: the hop megakernel on the
-f32, compact (bf16 vectors + int16 neighbors) and int8 tables, and the
-construction prune, edge-selection and gather-distance kernels.
+f32, compact (bf16) and int8 vector tables, and the construction prune,
+edge-selection and gather-distance kernels. The neighbor table goes in
+as the ``storage.EdgeRows`` form the serving path uploads for every
+neighbor codec.
 """
 import os
 
@@ -76,29 +78,28 @@ def _tables(s):
     }
 
 
+def _edges(s):
+    """The neighbor table as the kernels read it: ``storage.EdgeRows``,
+    which the serving path uploads once for every neighbor codec."""
+    K = (LOGN + 1) * M
+    return storage_mod.EdgeRows(
+        _spec(s, (N * -(-K // 128), 128), jnp.int32), LOGN + 1, M)
+
+
 @pytest.mark.parametrize("table", ["f32", "compact", "int8"])
 def test_hop_compiles(one_chip, table):
     s = one_chip
-    if table == "compact":
-        # int16 ids pack into the EdgeRows form inside the call
-        nbrs = _spec(s, (N, LOGN + 1, M), jnp.int16)
-    else:
-        # the serving path's upload: no per-call table copy
-        K = (LOGN + 1) * M
-        nbrs = storage_mod.EdgeRows(
-            _spec(s, (N * -(-K // 128), 128), jnp.int32), LOGN + 1, M)
     compiled = _compile(
         lambda q, t, e, u, L, R, v, x: hop_kernel_call(
             q, t, e, u, L, R, v, x, logn=LOGN, m_out=M),
-        _spec(s, (B, D), jnp.float32), _tables(s)[table], nbrs,
+        _spec(s, (B, D), jnp.float32), _tables(s)[table], _edges(s),
         _spec(s, (B, W), jnp.int32), _spec(s, (B * W,), jnp.int32),
         _spec(s, (B * W,), jnp.int32), _spec(s, (B, WORDS), jnp.uint32),
         _spec(s, (B, W), jnp.bool_),
     )
-    if table != "compact":
-        # visited rows (128 KiB per query) stream through VMEM blocks; what
-        # lands in HBM temp is only the small padded/bitcast operands
-        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    # visited rows (128 KiB per query) stream through VMEM blocks; what
+    # lands in HBM temp is only the small padded/bitcast operands
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 def test_prune_compiles(one_chip):
@@ -116,7 +117,7 @@ def test_edge_select_compiles(one_chip):
     _compile(
         lambda e, u, L, R: edge_select_kernel_call(
             e, u, L, R, logn=LOGN, m_out=M),
-        _spec(s, (N, LOGN + 1, M), jnp.int32), _spec(s, (F,), jnp.int32),
+        _edges(s), _spec(s, (F,), jnp.int32),
         _spec(s, (F,), jnp.int32), _spec(s, (F,), jnp.int32),
     )
 
@@ -127,4 +128,27 @@ def test_gather_dist_compiles(one_chip):
         gather_distance_kernel_call,
         _spec(s, (B, D), jnp.float32), _tables(s)["f32"],
         _spec(s, (B, W * M), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("table", ["compact", "int8"])
+def test_gather_dist_codec_compiles(one_chip, table):
+    """16/8-bit rows DMA as 8-row blocks and are picked on arrival: the
+    widest autotune tile fits VMEM, one f32 row per slot."""
+    s = one_chip
+    _compile(
+        lambda q, t, i: gather_distance_kernel_call(
+            q, t, i, block_b=16, block_m=256),
+        _spec(s, (B, D), jnp.float32), _tables(s)[table],
+        _spec(s, (B, 512), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("table", ["compact", "int8"])
+def test_prune_codec_compiles(one_chip, table):
+    s = one_chip
+    _compile(
+        lambda i, d, t: prune_kernel_call(i, d, t, m=M, block_b=16),
+        _spec(s, (2048, C), jnp.int32), _spec(s, (2048, C), jnp.float32),
+        _tables(s)[table],
     )
