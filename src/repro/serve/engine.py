@@ -43,7 +43,9 @@ import dataclasses
 import time
 from collections import deque
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import config as config_mod
 from repro.core.config import SearchConfig
@@ -133,10 +135,19 @@ def plan_flush(
     return out
 
 
-def run_search_batch(index, executor, reqs, kb, *, config=None, faults=None):
-    """Run one formed batch through the executor: value->rank mapping,
-    bucketed compile-cached search, original-id mapping. Returns
-    ``(orig_ids [B, kb], dists [B, kb])``.
+def run_search_batch(index, executor, reqs, kb, *, config=None, faults=None,
+                     flush=0):
+    """Run one formed batch (at most ``executor.max_batch`` requests)
+    through the executor: value->rank mapping, bucketed compile-cached
+    search, original-id mapping. Returns ``(orig_ids [B, kb], dists [B,
+    kb])``, and adds the batch's nodes expanded and rows gathered
+    (``SearchResult.n_hops`` and ``n_dists``) to ``executor.stats``.
+
+    Each phase is a profiler span carrying the batch's sequence number
+    ``flush``: ``serve.prep``, ``serve.launch`` (the executor call, which
+    only enqueues the program), ``serve.wait`` (the device's run, then its
+    ready signal), ``serve.fetch`` (one device-to-host copy of the four
+    result arrays) and ``serve.map``. None encloses another.
 
     The fault-injection hooks fire here — ``latency`` right before the
     executor call (an executor latency spike), ``flush_error`` before any
@@ -144,14 +155,23 @@ def run_search_batch(index, executor, reqs, kb, *, config=None, faults=None):
     if faults is not None:
         faults.maybe_latency()
         faults.maybe_flush_error()
-    q = np.stack([np.asarray(r.vector, np.float32) for r in reqs])
-    lo = np.array([r.lo for r in reqs])
-    hi = np.array([r.hi for r in reqs])
-    L, R = index.ranks_of(lo, hi)
-    res = executor.search_ranks(q, L, R, k=kb, config=config)
-    ids = np.asarray(res.ids)
-    dists = np.asarray(res.dists)
-    return index.original_ids(ids), dists
+    with TraceAnnotation("serve.prep", flush=flush):
+        q = np.stack([np.asarray(r.vector, np.float32) for r in reqs])
+        lo = np.array([r.lo for r in reqs])
+        hi = np.array([r.hi for r in reqs])
+        L, R = index.ranks_of(lo, hi)
+    with TraceAnnotation("serve.launch", flush=flush,
+                         bucket=executor.batch_bucket(len(reqs)), k=kb):
+        res = executor.search_ranks(q, L, R, k=kb, config=config)
+    with TraceAnnotation("serve.wait", flush=flush):
+        jax.block_until_ready(res)
+    with TraceAnnotation("serve.fetch", flush=flush):
+        ids, dists, hops, rows = jax.device_get(res)
+    executor.stats["nodes_expanded"] += int(hops.sum())
+    executor.stats["rows_gathered"] += int(rows.sum())
+    with TraceAnnotation("serve.map", flush=flush):
+        orig = index.original_ids(ids)
+    return orig, dists
 
 
 class ServingEngine:
@@ -254,7 +274,8 @@ class ServingEngine:
         t0 = time.perf_counter()
         reqs = [queue[i][0] for i in idxs]
         orig, dists = run_search_batch(
-            self.index, self.executor, reqs, kb, faults=self.faults
+            self.index, self.executor, reqs, kb, faults=self.faults,
+            flush=self._counts["batches"] + self._counts["flush_failures"],
         )
         t1 = time.perf_counter()
         self._counts["served"] += len(reqs)

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import config as config_mod
 from repro.core import knobs as knobs_mod
@@ -100,9 +101,12 @@ class SearchExecutor:
         self.closed = False
         self._cache: dict = {}   # (config, batch_bucket, k_bucket) -> exe
         self.seen_k_buckets: set[int] = set()
+        # nodes_expanded / rows_gathered: the served rows' n_hops / n_dists,
+        # added by serve/engine.py::run_search_batch as it fetches them
         self.stats = {
             "compiles": 0, "warmup_compiles": 0, "cache_hits": 0,
             "batches": 0, "queries": 0, "index_bytes": int(index.nbytes),
+            "nodes_expanded": 0, "rows_gathered": 0,
         }
         if warmup is None:
             warmup = knobs_mod.get_bool("REPRO_SERVE_WARMUP")
@@ -134,11 +138,11 @@ class SearchExecutor:
         d = self.index.dim
         q = jnp.zeros((bb, d), jnp.float32)
         z = jnp.zeros((bb,), jnp.int32)
-        lowered = search_mod._search_improvised_jit.lower(
-            self._vec, self._nbrs, q, z, z, self._rerank,
-            logn=self.index.logn, m_out=self.index.m, k=kb, config=cfg,
-        )
-        exe = lowered.compile()
+        with TraceAnnotation("serve.compile", bucket=bb, k=kb, warmup=warmup):
+            exe = search_mod._search_improvised_jit.lower(
+                self._vec, self._nbrs, q, z, z, self._rerank,
+                logn=self.index.logn, m_out=self.index.m, k=kb, config=cfg,
+            ).compile()
         self._cache[key] = exe
         self.stats["compiles"] += 1
         if warmup:

@@ -33,6 +33,14 @@ the same warmed ``SearchExecutor`` (ROADMAP's "millions of users" item):
     ``drain=False`` fails everything pending immediately. Nothing is ever
     silently dropped.
 
+Under a ``jax.profiler`` session the loop writes its host work as spans on
+the device trace's clock: ``serve.admit`` (a submit), ``serve.reap`` (a
+pass of the deadline watcher), and for each flush ``serve.form`` and
+``serve.post`` here, with the worker thread's spans of
+``run_search_batch`` between them, all carrying the flush's number.
+``stats`` adds ``queue_wait_s`` (summed over dispatched requests, submit
+to take) and the executor's ``nodes_expanded`` and ``rows_gathered``.
+
 Batch formation (``plan_flush``) and the batch runner
 (``run_search_batch``, which hosts the fault-injection hooks of
 ``serve/faults.py``) are shared with the sync engine. ``faults=None``
@@ -46,7 +54,7 @@ import dataclasses
 import time
 from collections import deque
 
-import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.config import SearchConfig, ServeConfig
 from repro.serve import faults as faults_mod
@@ -102,11 +110,12 @@ class AsyncServingEngine:
         self._reap_wake = asyncio.Event()   # reaper: deadlines changed
         self._space = asyncio.Event()       # blocked submitters: queue shrank
         self._idle = asyncio.Event()        # drain: nothing pending/in flight
-        self._latencies: deque[float] = deque(maxlen=8192)
+        # queue_wait_s: summed over dispatched requests, submit to take
         self._counts = {
             "submitted": 0, "served": 0, "rejected": 0, "shed": 0,
             "timeouts": 0, "failed": 0, "shutdown": 0, "dispatched": 0,
             "flushes": 0, "flush_failures": 0, "late_results": 0,
+            "queue_wait_s": 0.0,
         }
 
     # -- lifecycle -----------------------------------------------------------
@@ -168,23 +177,24 @@ class AsyncServingEngine:
         resolves through the request's future."""
         if self.closed:
             raise ShutdownError("AsyncServingEngine is closed")
-        validate_request(req, dim=self.index.dim, ef=self.config.ef)
-        self._ensure_started()
-        now = time.monotonic()
-        budget = self.serve.deadline_s if deadline_s is None \
-            else float(deadline_s)
-        if not budget > 0.0:
-            raise ValueError(f"deadline_s must be > 0, got {deadline_s}")
-        deadline = now + budget
-        await self._admit(deadline)
-        p = _Pending(
-            req, asyncio.get_running_loop().create_future(),
-            time.monotonic(), deadline,
-        )
-        self._pending.append(p)
-        self._counts["submitted"] += 1
-        self._wake.set()
-        self._reap_wake.set()
+        with TraceAnnotation("serve.admit"):
+            validate_request(req, dim=self.index.dim, ef=self.config.ef)
+            self._ensure_started()
+            now = time.monotonic()
+            budget = self.serve.deadline_s if deadline_s is None \
+                else float(deadline_s)
+            if not budget > 0.0:
+                raise ValueError(f"deadline_s must be > 0, got {deadline_s}")
+            deadline = now + budget
+            await self._admit(deadline)
+            p = _Pending(
+                req, asyncio.get_running_loop().create_future(),
+                time.monotonic(), deadline,
+            )
+            self._pending.append(p)
+            self._counts["submitted"] += 1
+            self._wake.set()
+            self._reap_wake.set()
         return await p.fut
 
     async def _admit(self, deadline: float):
@@ -253,6 +263,13 @@ class AsyncServingEngine:
                     pass
 
     async def _flush_once(self):
+        """Take up to ``max_batch`` queued requests and run them, one
+        batch per k bucket, each a flush numbered by ``_counts["flushes"]``.
+        A flush's ``serve.form`` span runs from here (the first batch of a
+        take) or from the end of the previous batch, up to its hand-off to
+        the worker thread; its ``serve.post`` span resolves its futures."""
+        form = TraceAnnotation("serve.form", flush=self._counts["flushes"])
+        form.__enter__()
         take: list[_Pending] = []
         while self._pending and len(take) < self.executor.max_batch:
             p = self._pending.popleft()
@@ -260,19 +277,30 @@ class AsyncServingEngine:
                 take.append(p)
         self._space.set()
         if not take:
+            form.__exit__(None, None, None)
             return
+        now = time.monotonic()
+        self._counts["queue_wait_s"] += sum(now - p.t_submit for p in take)
         plans = plan_flush(
             [p.req for p in take], self.config, self.executor.max_batch
         )
         self._inflight.update(take)
         for kb, idxs in plans:
+            flush = self._counts["flushes"]
+            if form is None:
+                form = TraceAnnotation("serve.form", flush=flush)
+                form.__enter__()
             batch = [take[i] for i in idxs]
             self._counts["flushes"] += 1
             self._counts["dispatched"] += len(batch)
+            form.set_metadata(rows=len(batch))
+            form.__exit__(None, None, None)
+            form = None
             try:
                 orig, dists = await asyncio.to_thread(
                     run_search_batch, self.index, self.executor,
                     [p.req for p in batch], kb, faults=self.faults,
+                    flush=flush,
                 )
             except Exception as e:  # noqa: BLE001 — isolate to this batch
                 self._counts["flush_failures"] += 1
@@ -282,18 +310,21 @@ class AsyncServingEngine:
                         self._counts["failed"] += 1
                         p.fut.set_exception(e)
                 continue
-            t1 = time.monotonic()
-            for row, p in enumerate(batch):
-                self._inflight.discard(p)
-                if p.fut.done():   # timed out while the flush ran
-                    self._counts["late_results"] += 1
-                    continue
-                lat = t1 - p.t_submit
-                self._latencies.append(lat)
-                self._counts["served"] += 1
-                p.fut.set_result(Result(
-                    orig[row, : p.req.k], dists[row, : p.req.k], lat
-                ))
+            with TraceAnnotation("serve.post", flush=flush) as post:
+                t1 = time.monotonic()
+                late = 0
+                for row, p in enumerate(batch):
+                    self._inflight.discard(p)
+                    if p.fut.done():   # timed out while the flush ran
+                        late += 1
+                        continue
+                    p.fut.set_result(Result(
+                        orig[row, : p.req.k], dists[row, : p.req.k],
+                        t1 - p.t_submit,
+                    ))
+                self._counts["served"] += len(batch) - late
+                self._counts["late_results"] += late
+                post.set_metadata(served=len(batch) - late, late=late)
         self._maybe_idle()
 
     async def _reap_loop(self):
@@ -301,20 +332,21 @@ class AsyncServingEngine:
         expired in-flight ones — independent of the flusher, so a latency
         spike inside a flush cannot delay timeout delivery."""
         while True:
-            now = time.monotonic()
-            nxt = self._compact_queue(now)
-            for p in self._inflight:
-                if p.fut.done():
-                    continue
-                if p.deadline <= now:
-                    self._counts["timeouts"] += 1
-                    p.fut.set_exception(DeadlineExceededError(
-                        "deadline exceeded while request was in flight"
-                    ))
-                elif nxt is None or p.deadline < nxt:
-                    nxt = p.deadline
-            self._maybe_idle()
-            self._reap_wake.clear()
+            with TraceAnnotation("serve.reap", inflight=len(self._inflight)):
+                now = time.monotonic()
+                nxt = self._compact_queue(now)
+                for p in self._inflight:
+                    if p.fut.done():
+                        continue
+                    if p.deadline <= now:
+                        self._counts["timeouts"] += 1
+                        p.fut.set_exception(DeadlineExceededError(
+                            "deadline exceeded while request was in flight"
+                        ))
+                    elif nxt is None or p.deadline < nxt:
+                        nxt = p.deadline
+                self._maybe_idle()
+                self._reap_wake.clear()
             try:
                 if nxt is None:
                     await self._reap_wake.wait()
@@ -367,18 +399,10 @@ class AsyncServingEngine:
     @property
     def stats(self) -> dict:
         ex = self.executor.stats
-        lat = np.fromiter(self._latencies, float) if self._latencies else None
-        pct = {
-            f"latency_p{p}": float(np.percentile(lat, p)) if lat is not None
-            else 0.0
-            for p in (50, 95, 99)
-        }
         return {
             **self._counts,
             "queue_depth": len(self._pending),
-            "compiles": ex["compiles"],
-            "warmup_compiles": ex["warmup_compiles"],
-            "cache_hits": ex["cache_hits"],
-            "index_bytes": ex["index_bytes"],
-            **pct,
+            **{k: ex[k] for k in (
+                "compiles", "warmup_compiles", "cache_hits", "index_bytes",
+                "nodes_expanded", "rows_gathered")},
         }
