@@ -10,28 +10,38 @@ hop so the frontier never leaves VMEM:
 
   1. **edge gather** — per ``(bb)`` query tile the neighbor table stays
      un-blocked in ``ANY``/HBM space in its ``storage.EdgeRows`` form and
-     the kernel DMAs each of the ``bb*W`` frontier nodes' ``K =
-     (logn+1)*m`` edges (``Rw`` whole 128-lane rows) into a VMEM scratch
-     (software-pipelined, up to ``window`` copies in flight, ``-1``
-     frontier slots skipped by predication);
+     the kernel DMAs each live frontier node's ``K = (logn+1)*m`` edges
+     (``Rw`` whole 128-lane rows) into a VMEM scratch: the ``u >= 0``
+     slots of the ``bb*W`` are listed in SMEM first and the
+     software-pipelined loop (up to ``window`` copies in flight) runs over
+     that list alone;
   2. **edge selection** — the ``segment_tree.scan_mask`` closed form
      (``ref.edge_scan_valid``) plus the *lazy* O(m_out·K) dedup: ``m_out``
      masked-argmin steps, each wiping every position holding the id it just
      selected — no ``[K, K]`` equality matrix, so VMEM stays flat in K; the
-     selected ids then DMA to SMEM, where the next two phases index with
-     them as scalars;
+     selected ids, with each node's live span (the length of its prefix of
+     selected ids, 0 where its expansion is off), then DMA to SMEM, where
+     the next two phases index with them as scalars, and ``nbr`` is stored
+     from them in one vector store;
   3. **visited test-and-set** — the query tile's bitset rows (int32 words)
-     live in VMEM for the whole launch. Slots run one at a time in
-     row-major order: each reads its word as the aligned 128-word column
-     block of all ``bb`` rows, tests the bit, and sets it only when new, so
-     a repeat of an id in the same row finds its bit set — the
-     first-occurrence rule of ``core/bitset.py``. (Mosaic lowers neither a
-     general gather over the row nor an unsigned reduction.);
-  4. **vector gather + distance** — the newly-visited ids DMA their vector
-     rows straight from the un-blocked table into one f32 VMEM row each
-     (16/8-bit tables land as 8-row blocks in a staging ring and keep only
-     the row, ``kernels/rowdma.py``) and one MXU matmul emits masked f32
-     distances, exactly the ``gather_distance.py`` structure.
+     live in VMEM for the whole launch. Only the live slots run, one at a
+     time in row-major order (node by node over its span): each reads its
+     word as the aligned 128-word column block of all ``bb`` rows, tests
+     the bit, and sets it only when new, so a repeat of an id in the same
+     row finds its bit set — the first-occurrence rule of
+     ``core/bitset.py``. (Mosaic lowers neither a general gather over the
+     row nor an unsigned reduction.) Each slot writes its new id (or -1)
+     to an SMEM row that comes back to VMEM once as ``nvalid``, and each
+     newly visited slot is listed for phase 4;
+  4. **vector gather + distance** — the listed new ids DMA their vector
+     rows straight from the un-blocked table, each into the f32 VMEM row of
+     its own slot (16/8-bit tables land as 8-row blocks in a staging ring
+     and keep only the row, ``kernels/rowdma.py``), so the DMA window holds
+     only live copies, and one MXU matmul emits masked f32 distances,
+     exactly the ``gather_distance.py`` structure.
+
+Every loop over slots is bounded by a count the kernel takes from its own
+input, so a dead slot costs no iteration.
 
 Semantics are ``kernels/ref.py::hop`` (select_edges -> bitset.test_and_set
 -> gather_dist): integer outputs (edges, newly-visited mask, bitset words)
@@ -72,15 +82,16 @@ def _hop_kernel(
     vis_ref,     # VMEM [bb, words] int32 (query tile's bitset rows)
     nbrs_ref,    # ANY  [n*Rw, 128]  (packed edge rows, never blocked)
     table_ref,   # ANY  vector/code table in ``rowdma.row_table`` form
-    *refs,       # [aux_ref], outputs, ebuf, esel, esel_s, gid_s, xbuf,
-                 # [stage], [sstage], sems, [sems2]
+    *refs,       # [aux_ref], outputs, ebuf, esel, esel_s, gid_s, fr_s,
+                 # new_s, xbuf, [stage], [sstage], sems, [sems2]
     bb, W, K, Rw, m, m_out, logn, skip_layers, metric, window,
     codec, blocked, dp, pq_m, pq_dsub,
 ):
     it = iter(refs)
     aux_ref = next(it) if codec is not None else None
     nbr_out, dist_out, nvalid_out, vis_out = (next(it) for _ in range(4))
-    ebuf, esel, esel_s, gid_s, xbuf = (next(it) for _ in range(5))
+    ebuf, esel, esel_s, gid_s, fr_s, new_s, xbuf = (
+        next(it) for _ in range(7))
     stage = next(it) if blocked else None
     sstage = next(it) if codec == "int8" else None
     sems = next(it)
@@ -89,13 +100,23 @@ def _hop_kernel(
     F = bb * W
     T = bb * WM
 
-    # -- 1. pipelined edge-block gather (one Rw-row DMA per frontier node) --
-    def edge_u(t):
-        return meta_smem[t // W, t % W]
+    # -- 1. pipelined edge-block gather (one Rw-row DMA per live node) ------
+    # The frontier slots with u >= 0 are listed in SMEM first, so the DMA
+    # loop runs over them alone and its window holds only live copies; an
+    # unlisted slot's ebuf row is never read as an edge (u < 0 fails
+    # edge_scan_valid).
+    def edge_u(f):
+        return meta_smem[f // W, f % W]
 
-    _rows.gather(F, window, edge_u, lambda t: [pltpu.make_async_copy(
-        nbrs_ref.at[pl.ds(edge_u(t) * Rw, Rw)], ebuf.at[t],
-        sems.at[t % window])])
+    def list_node(f, count):
+        fr_s[count] = f
+        return count + (edge_u(f) >= 0).astype(jnp.int32)
+
+    n_nodes = jax.lax.fori_loop(0, F, list_node, jnp.int32(0))
+    _rows.gather(n_nodes, window, lambda t: edge_u(fr_s[t]),
+                 lambda t: [pltpu.make_async_copy(
+                     nbrs_ref.at[pl.ds(edge_u(fr_s[t]) * Rw, Rw)],
+                     ebuf.at[fr_s[t]], sems.at[t % window])])
 
     # -- 2. edge selection: scan-mask validity + lazy O(m_out*K) dedup ------
     col = col_vmem[...]
@@ -122,55 +143,79 @@ def _hop_kernel(
         outs.append(out_t)
         taken = (flat == out_t) & (prio < _BIG)
         prio = jnp.where(sel | taken, _BIG, prio)
-    # the selected ids go to SMEM: the visited test-and-set and the vector
-    # DMAs below index with them as scalars
-    esel[...] = jnp.concatenate(outs, axis=1)             # [F, m_out]
+    sel_ids = jnp.concatenate(outs, axis=1)               # [F, m_out]
+    # a node's selected ids are a prefix of its row (a step that finds
+    # nothing left selects -1, and so does every later step); the number
+    # of slots phase 3 visits is the length of that prefix, 0 for a node
+    # whose expansion is off
+    j1 = jax.lax.broadcasted_iota(jnp.int32, (F, m_out), 1) + 1
+    span = jnp.max(jnp.where(sel_ids >= 0, j1, 0), axis=1, keepdims=True)
+    span = jnp.where(col[:, 3:4] != 0, span, 0)           # [F, 1]
+    # the selected ids and the spans go to SMEM: the visited test-and-set
+    # and the vector DMAs below index with them as scalars
+    esel[...] = jnp.concatenate([sel_ids, span], axis=1)  # [F, m_out+1]
     to_smem = pltpu.make_async_copy(esel, esel_s, sems.at[0])
     to_smem.start()
+    # row b of nbr_out holds frontier rows b*W .. b*W+W-1 side by side
+    nbr_out[...] = jnp.concatenate(
+        [esel[pl.ds(w, bb, stride=W), pl.ds(0, m_out)] for w in range(W)],
+        axis=1)                                           # [bb, WM]
+    # gid_s[b, c], slot c of query b's row, is the slot's id where it is
+    # newly visited and -1 elsewhere: set to -1 through nvalid_out, the
+    # slots of the listed spans written below
+    nvalid_out[...] = jnp.full((bb, WM), -1, jnp.int32)
     to_smem.wait()
+    to_gid = pltpu.make_async_copy(nvalid_out, gid_s, sems.at[0])
+    to_gid.start()
+    to_gid.wait()
 
     # -- 3. visited test-and-set, bitset rows resident in VMEM --------------
-    # One slot at a time, in row-major slot order, so the first occurrence
-    # of an id within a row wins (the second finds its bit set). A slot's
-    # word is read as the aligned 128-word column block of all bb rows.
+    # Live slots only, one at a time, in row-major slot order (node by
+    # node, each node's span in order), so the first occurrence of an id
+    # within a row wins (the second finds its bit set). A slot's word is
+    # read as the aligned 128-word column block of all bb rows. Each newly
+    # visited slot is listed in new_s for phase 4.
     vis_out[...] = vis_ref[...]
     sub = jax.lax.broadcasted_iota(jnp.int32, (bb, 128), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (bb, 128), 1)
-    slot = (jax.lax.broadcasted_iota(jnp.int32, (bb, WM), 0) * WM
-            + jax.lax.broadcasted_iota(jnp.int32, (bb, WM), 1))
 
-    def visit(t, carry):
-        edges, nvalid = carry
-        f, j = t // m_out, t % m_out
+    def node(f, count):
         b, w = f // W, f % W
-        idv = esel_s[f, j]
-        ok = (idv >= 0) & (meta_smem[b, 3 * W + w] != 0)
-        safe = jnp.maximum(idv, 0)
-        word = safe >> 5
-        base = pl.multiple_of((word >> 7) * 128, 128)
-        blk = vis_out[:, pl.ds(base, 128)]                # [bb, 128]
-        hit = (sub == b) & (lane == (word & 127))
-        bit = (jnp.sum(jnp.where(hit, blk, 0)) >> (safe & 31)) & 1
-        new = ok & (bit == 0)
-        gid_s[t] = jnp.where(new, idv, -1)
 
-        @pl.when(new)
-        def _():
-            vis_out[:, pl.ds(base, 128)] = blk | jnp.where(
-                hit, jnp.int32(1) << (safe & 31), 0)
+        def visit(j, count):
+            idv = esel_s[f, j]
+            safe = jnp.maximum(idv, 0)
+            word = safe >> 5
+            base = pl.multiple_of((word >> 7) * 128, 128)
+            blk = vis_out[:, pl.ds(base, 128)]            # [bb, 128]
+            hit = (sub == b) & (lane == (word & 127))
+            bit = (jnp.sum(jnp.where(hit, blk, 0)) >> (safe & 31)) & 1
+            new = (idv >= 0) & (bit == 0)
+            gid_s[b, w * m_out + j] = jnp.where(new, idv, -1)
+            new_s[count] = f * m_out + j
 
-        edges = jnp.where(slot == t, idv, edges)
-        nvalid = jnp.where(slot == t, new.astype(jnp.int32), nvalid)
-        return edges, nvalid
+            @pl.when(new)
+            def _():
+                vis_out[:, pl.ds(base, 128)] = blk | jnp.where(
+                    hit, jnp.int32(1) << (safe & 31), 0)
 
-    zero = jnp.zeros((bb, WM), jnp.int32)
-    edges, nvalid = jax.lax.fori_loop(0, T, visit, (zero, zero))
-    nbr_out[...] = edges
+            return count + new.astype(jnp.int32)
+
+        return jax.lax.fori_loop(0, esel_s[f, m_out], visit, count)
+
+    n_new = jax.lax.fori_loop(0, F, node, jnp.int32(0))
+    to_vmem = pltpu.make_async_copy(gid_s, nvalid_out, sems.at[0])
+    to_vmem.start()
+    to_vmem.wait()
+    nvalid = (nvalid_out[...] >= 0).astype(jnp.int32)     # [bb, WM]
     nvalid_out[...] = nvalid
 
     # -- 4. pipelined vector gather for the newly-visited ids ---------------
+    # over the n_new listed slots only; slot s = f*m_out + j of the list
+    # is row s of xbuf (row-major [bb, WM] order), as the distances expect
     def vec_id(t):
-        return gid_s[t]
+        s_ = new_s[t]
+        return esel_s[s_ // m_out, s_ % m_out]
 
     def scale_copy(t):
         # ids are discovered in-kernel, so the per-row scale rides a
@@ -186,9 +231,10 @@ def _hop_kernel(
     # codec decode, in-register (DESIGN.md §9): int8 rows arrive scaled,
     # PQ rows as their codes
     int8 = codec == "int8"
-    _rows.gather_rows(table_ref, xbuf, stage, sems, T, window, vec_id,
+    _rows.gather_rows(table_ref, xbuf, stage, sems, n_new, window, vec_id,
                       scale=scale if int8 else None,
-                      extra=(scale_copy,) if int8 else ())
+                      extra=(scale_copy,) if int8 else (),
+                      row=lambda t: new_s[t])
 
     # -- distance: one MXU pass, keep the diagonal query<->row pairing ------
     q = q_ref[...].astype(jnp.float32)                    # [bb, dp]
@@ -309,8 +355,10 @@ def hop_kernel_call(
 
     scratch_shapes = [
         pltpu.VMEM((bb * W, Rw, 128), jnp.int32),
-        pltpu.VMEM((bb * W, m_out), jnp.int32),
-        pltpu.SMEM((bb * W, m_out), jnp.int32),
+        pltpu.VMEM((bb * W, m_out + 1), jnp.int32),
+        pltpu.SMEM((bb * W, m_out + 1), jnp.int32),
+        pltpu.SMEM((bb, WM), jnp.int32),
+        pltpu.SMEM((bb * W,), jnp.int32),
         pltpu.SMEM((bb * WM,), jnp.int32),
         pltpu.VMEM((bb * WM, tp.shape[-1]), jnp.float32),
     ]

@@ -61,7 +61,9 @@ def gather(total, window, idx, copies, arrive=None):
     ``window`` slots in flight; ``copies(t)`` gives slot ``t``'s DMA
     descriptors (semaphore ``t % window``), skipped when ``idx(t) < 0``.
     ``arrive(t)``, when given, runs right after slot ``t``'s copies land
-    and before slot ``t + window`` reuses its semaphore (and staging)."""
+    and before slot ``t + window`` reuses its semaphore (and staging).
+    ``total`` is a Python int, or an int32 scalar the kernel counted
+    itself (the hop's live slots, listed in SMEM)."""
     def start(t):
         @pl.when(idx(t) >= 0)
         def _():
@@ -90,23 +92,27 @@ def gather(total, window, idx, copies, arrive=None):
         wait(t)
         return carry
 
-    jax.lax.fori_loop(max(0, total - window), total, drain, 0)
+    first = (max(0, total - window) if isinstance(total, int)
+             else jnp.maximum(total - window, 0))
+    jax.lax.fori_loop(first, total, drain, 0)
 
 
 def gather_rows(table_ref, xbuf, stage, sems, total, window, idx,
-                scale=None, extra=()):
-    """Gather table row ``idx(t)`` into ``xbuf[t]`` (f32 ``[total, w]``)
-    for every slot with ``idx(t) >= 0``; other slots hold garbage the
-    caller masks. ``stage`` is ``None`` for a one-tile 32-bit table (the
-    row DMAs straight into its slot), else the ``[window, ROWS, w]``
-    staging ring the row's 8-row block lands in. ``scale(t)`` (a ``[1, 1]``
-    f32, read after the copies land) multiplies the picked row; ``extra``
-    adds per-slot DMA descriptors ``e(t)`` that ride on the same
-    pipeline."""
+                scale=None, extra=(), row=None):
+    """Gather table row ``idx(t)`` into ``xbuf[row(t)]`` (f32 ``[.., w]``;
+    ``row`` defaults to ``t``) for every ``t < total`` with ``idx(t) >=
+    0``; other rows hold garbage the caller masks. ``stage`` is ``None``
+    for a one-tile 32-bit table (the row DMAs straight into its slot), else
+    the ``[window, ROWS, w]`` staging ring the row's 8-row block lands in.
+    ``scale(t)`` (a ``[1, 1]`` f32, read after the copies land) multiplies
+    the picked row; ``extra`` adds per-slot DMA descriptors ``e(t)`` that
+    ride on the same pipeline."""
+    dst_row = row if row is not None else (lambda t: t)
+
     def copies(t):
         i = idx(t)
         if stage is None:
-            src, dst = table_ref.at[i], xbuf.at[t]
+            src, dst = table_ref.at[i], xbuf.at[dst_row(t)]
         else:
             src, dst = table_ref.at[i >> 3], stage.at[t % window]
         return [pltpu.make_async_copy(src, dst, sems.at[t % window]),
@@ -115,11 +121,11 @@ def gather_rows(table_ref, xbuf, stage, sems, total, window, idx,
     def arrive(t):
         blk = stage[t % window].astype(jnp.float32)       # [ROWS, w]
         sub = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
-        row = jnp.sum(jnp.where(sub == (idx(t) & (ROWS - 1)), blk, 0.0),
-                      axis=0, keepdims=True)              # [1, w]
+        picked = jnp.sum(jnp.where(sub == (idx(t) & (ROWS - 1)), blk, 0.0),
+                         axis=0, keepdims=True)           # [1, w]
         if scale is not None:
-            row = row * scale(t)
-        xbuf[pl.ds(t, 1), :] = row
+            picked = picked * scale(t)
+        xbuf[pl.ds(dst_row(t), 1), :] = picked
 
     gather(total, window, idx, copies, None if stage is None else arrive)
 

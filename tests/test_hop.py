@@ -10,6 +10,8 @@ Plus the dispatch guards: unknown tokens raise, ``REPRO_HOP_IMPL`` wins
 over ``REPRO_IMPL``, and a global ``REPRO_IMPL=legacy`` falls back to the
 composed path instead of erroring.
 """
+import itertools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -18,6 +20,7 @@ from repro.core import SearchConfig, bitset
 from repro.core import storage as storage_mod
 from repro.core.search import beam_search
 from repro.kernels import ops
+from repro.kernels import ref as _ref
 from repro.kernels.edge_select import edge_select_kernel_call
 from repro.kernels.hop import hop_kernel_call
 
@@ -163,6 +166,121 @@ def test_hop_kernel_block_sizes():
         got = hop_kernel_call(*args, block_b=bb, window=w,
                               interpret=True, **p["kw"])
         _assert_hop_equal(got, base)
+
+
+# ---------------------------------------------------------------------------
+# live-slot loops: the kernel visits and gathers the live slots alone
+# ---------------------------------------------------------------------------
+
+_LN, _LB, _LW, _LM = 200, 10, 2, 8      # n, B (two tiles of 8), W, m=m_out
+
+
+def _live_case(case):
+    """``(lists, exp_ok, pre)`` of one live-slot edge case: frontier
+    slot f = b*W + w expands node 100 + f, whose every layer holds
+    ``lists[f]`` (so exactly its distinct ids are selected, in order);
+    ``None`` makes the slot u = -1. ``pre`` is a list of (row, id)
+    visited before the hop. Unlisted slots of the batch are dead."""
+    F = _LB * _LW
+    fresh = itertools.cycle(range(100))     # distinct within any row
+
+    def take(c):
+        return [next(fresh) for _ in range(c)]
+
+    exp_ok = np.ones((_LB, _LW), bool)
+    pre = []
+    if case == "no_live_exp":
+        lists = [take(_LM) for _ in range(F)]
+        exp_ok[:] = False
+    elif case == "no_live_u":
+        lists = [None] * F
+    elif case == "all_live":            # every slot of tile 0 new
+        lists = [take(_LM) for _ in range(8 * _LW)] + [None] * (F - 16)
+    elif case in ("one_live", "below_window", "at_window", "above_window"):
+        # live copies in tile 0 against the DMA window of 4: 1, 3, 4, 9;
+        # in above_window one node has u >= 0 but selects nothing
+        counts = {"one_live": [1], "below_window": [2, 1],
+                  "at_window": [4], "above_window": [8, 1, 0]}[case]
+        lists = [take(c) for c in counts] + [None] * (F - len(counts))
+    elif case == "repeated_ids":
+        # row 0: 6 and 5 come twice (only the first is new), 5 once more
+        # in one node; row 1: 5 again (new in its own bitset) and 40
+        # (visited before); row 2: expansion off
+        lists = [[5, 6, 7, 5], [6, 9, 5], [5, 40], [41], [5, 6], [7]]
+        lists += [None] * (F - len(lists))
+        pre = [(1, 40), (3, 9)]
+        exp_ok[2] = False
+    elif case == "ids_0_and_n-1":
+        # the bitset's first and last bits, and a word's edges
+        lists = [[0, _LN - 1], [31, 32], [_LN - 1, 1, 0], None,
+                 [_LN - 1], [0]]
+        lists += [None] * (F - len(lists))
+        pre = [(2, 0)]
+    else:                               # "mixed": random lists
+        rng = np.random.default_rng(23)
+        lists = [None if rng.random() < 0.3 else
+                 list(rng.integers(-1, _LN, size=_LM)) for _ in range(F)]
+        exp_ok = rng.random((_LB, _LW)) < 0.7
+        pre = [(int(b), int(i)) for b, i in zip(
+            rng.integers(0, _LB, 40), rng.integers(0, _LN, 40))]
+    return lists, exp_ok, pre
+
+
+def _live_problem(case, codec, d=24):
+    lists, exp_ok, pre = _live_case(case)
+    rng = np.random.default_rng(29)
+    logn = int(np.ceil(np.log2(_LN)))
+    nbrs = np.full((_LN, logn + 1, _LM), -1, np.int32)
+    u = np.full((_LB * _LW,), -1, np.int32)
+    for f, ids in enumerate(lists):
+        if ids is not None:
+            u[f] = 100 + f
+            nbrs[100 + f, :, :len(ids)] = ids
+    vecs = rng.standard_normal((_LN, d)).astype(np.float32)
+    cfg = {"f32": storage_mod.StorageConfig(vector_dtype="float32"),
+           "bf16": storage_mod.StorageConfig(vector_dtype="bfloat16"),
+           "int8": storage_mod.StorageConfig.int8(),
+           "pq": storage_mod.StorageConfig.pq()}[codec]
+    table = storage_mod.as_device(storage_mod.encode_vectors(vecs, cfg))
+    visited = bitset.make(_LB, _LN)
+    if pre:
+        rows, ids = zip(*pre)
+        ids_b = np.full((_LB, len(pre)), -1, np.int32)
+        ids_b[list(rows), np.arange(len(pre))] = ids
+        visited, _ = bitset.test_and_set(
+            visited, jnp.asarray(ids_b), jnp.asarray(ids_b >= 0))
+    q = jnp.asarray(rng.standard_normal((_LB, d)), jnp.float32)
+    full = jnp.full((_LB * _LW,), _LN - 1, jnp.int32)
+    args = (q, table, jnp.asarray(nbrs), jnp.asarray(u).reshape(_LB, _LW),
+            jnp.zeros_like(full), full, visited, jnp.asarray(exp_ok))
+    return args, dict(logn=logn, m_out=_LM)
+
+
+@pytest.mark.parametrize("case, codec", [
+    ("no_live_exp", "f32"), ("no_live_u", "f32"), ("all_live", "f32"),
+    ("one_live", "f32"), ("below_window", "f32"), ("at_window", "f32"),
+    ("above_window", "f32"), ("repeated_ids", "f32"),
+    ("ids_0_and_n-1", "f32"), ("mixed", "f32"),
+    ("below_window", "bf16"), ("above_window", "bf16"), ("mixed", "bf16"),
+    ("below_window", "int8"), ("mixed", "int8"), ("mixed", "pq"),
+])
+def test_hop_live_slots(case, codec):
+    """The megakernel runs its edge DMAs, visited test-and-set and row DMAs
+    over the live slots alone; on a tile with none, with all of them, with
+    fewer, as many and more live row copies than the DMA window, with ids
+    repeated in and across rows and at the bitset's ends, and on the
+    blocked and quantized tables, it still matches ``ref.hop``: integer
+    outputs bit for bit, distances to f32 tolerance."""
+    args, kw = _live_problem(case, codec)
+    want = _ref.hop(*args, **kw)
+    got = ops.hop(*args, impl="pallas", window=4, **kw)
+    _assert_hop_equal(got, want, dist_tol=1e-5 if codec == "f32" else 1e-4)
+    # the rows each case gathers, as it sets out to
+    new_rows = {"no_live_exp": 0, "no_live_u": 0, "all_live": 128,
+                "one_live": 1, "below_window": 3, "at_window": 4,
+                "above_window": 9, "repeated_ids": 4 + 2}
+    if case in new_rows:
+        assert np.asarray(want[2]).sum() == new_rows[case]
 
 
 # ---------------------------------------------------------------------------
